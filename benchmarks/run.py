@@ -6,7 +6,8 @@
 Prints ``name,us_per_call,derived`` CSV. ``--json-out DIR`` additionally
 writes every structured payload (``Csv.add_json``) as
 ``DIR/BENCH_<name>.json`` — the artifacts CI uploads and
-``make_report.py`` renders."""
+``make_report.py`` renders. A module that raises still gets an
+``<name>/ERROR`` row, the others still run, and the exit code is 1."""
 from __future__ import annotations
 
 import argparse
@@ -34,7 +35,7 @@ MODULES = [
 ]
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of: "
@@ -45,6 +46,7 @@ def main(argv=None) -> None:
     only = set(args.only.split(",")) if args.only else None
 
     csv = Csv()
+    failed = []
     import importlib
     for key, modname in MODULES:
         if only is not None and key not in only:
@@ -54,6 +56,7 @@ def main(argv=None) -> None:
             mod.run(csv)
         except Exception as e:  # noqa: BLE001 — report, keep going
             csv.add(f"{key}/ERROR", 0.0, f"{type(e).__name__}")
+            failed.append(key)
             import traceback
             traceback.print_exc()
     csv.emit()
@@ -62,7 +65,8 @@ def main(argv=None) -> None:
         log = obs_log.get_logger("bench")
         for p in csv.write_json(args.json_out):
             log.info("artifact_written", path=str(p))
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

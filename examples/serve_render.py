@@ -30,11 +30,13 @@ def main():
     ap.add_argument("--cameras", type=int, default=3)
     ap.add_argument("--shard", action="store_true",
                     help="pixel-parallel shard_map over the local mesh")
+    ap.add_argument("--log2-table-size", type=int, default=14,
+                    help="hash-table size (Table I: 19, gia 24)")
     args = ap.parse_args()
     serve_render(args.app, args.encoding, train_steps=args.train_steps,
                  n_requests=args.requests, use_pallas=args.pallas,
                  n_scenes=args.scenes, n_cameras=args.cameras,
-                 shard=args.shard)
+                 shard=args.shard, log2_table_size=args.log2_table_size)
 
 
 if __name__ == "__main__":

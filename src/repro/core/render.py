@@ -9,7 +9,9 @@ Compositing follows classical emission-absorption volume rendering
 (paper refs [7], [11], [40]): alpha_i = 1 - exp(-sigma_i * dt_i),
 T_i = prod_{j<i}(1 - alpha_j), C = sum_i T_i * alpha_i * c_i. The XLA
 and Pallas composites share one transmittance formulation —
-``exp(cumsum(-sigma*dt))`` — so the two routes agree bit-for-bit.
+``exp(-prefix_sum(sigma*dt))`` — and differ only in how the prefix sum
+is ordered (a scan here, a triangular matmul in the kernel), so the two
+routes agree to a few f32 ulps.
 
 ``render_rays`` optionally runs occupancy-culled: samples in empty
 space or behind an opaque prefix are compacted away and only a *static*
@@ -145,13 +147,12 @@ def composite(rgb: jnp.ndarray, sigma: jnp.ndarray, dts: jnp.ndarray
 
     rgb (R, S, 3), sigma (R, S), dts (R, S) -> (pixel (R, 3), opacity (R,)).
 
-    Transmittance is realized as ``exp(cumsum(-sigma*dt))`` — the exact
-    formulation of the Pallas ``ray_march`` kernel (cumsum is the
-    TPU-native scan primitive; since ``1-alpha == exp(-sigma*dt)``
-    exactly, no ``log`` call and no epsilon are needed, and opaque
-    samples stay finite). Keeping one formulation on both routes makes
-    the XLA/Pallas composite parity bit-for-bit instead of
-    epsilon-noise-tolerant.
+    Transmittance is realized as ``exp(cumsum(-sigma*dt))`` — the
+    formulation of the Pallas ``ray_march`` kernel (since
+    ``1-alpha == exp(-sigma*dt)`` exactly, no ``log`` call and no
+    epsilon are needed, and opaque samples stay finite). The kernel sums
+    the same prefix as a triangular matmul, so the XLA/Pallas parity is
+    a few f32 ulps rather than epsilon-noise-tolerant.
     """
     alpha = 1.0 - jnp.exp(-sigma * dts)                       # (R, S)
     log1m = -sigma * dts                                      # log(1-alpha)

@@ -22,8 +22,8 @@ def is_quantized_dtype(dtype) -> bool:
 
 
 def default_interpret() -> bool:
-    """Pallas TPU kernels run in interpret mode off-TPU (this container is
-    CPU-only; the TPU is the *target*, interpret validates the body)."""
+    """Pallas TPU kernels compile with Mosaic on a TPU backend and run in
+    interpret mode on any other backend (CPU tests validate the body)."""
     return not on_tpu()
 
 

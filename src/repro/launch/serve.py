@@ -6,6 +6,8 @@
     the assigned architectures.
 
   PYTHONPATH=src python -m repro.launch.serve --mode render --app gia
+  PYTHONPATH=src python -m repro.launch.serve --mode render --app nvr \
+      --log2-table-size 14        # a CPU-sized table; default is Table I
   PYTHONPATH=src python -m repro.launch.serve --mode lm --arch olmoe-1b-7b --reduced
 """
 from __future__ import annotations
@@ -18,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import registry
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.obs import log as obs_log
 from repro.obs.trace import TRACER
@@ -33,10 +36,15 @@ def serve_render(app: str = "gia", encoding: str = "hash",
                  occupancy: bool = False,
                  sample_budget: int | None = None,
                  quant: str | None = None,
-                 metrics_out: str | None = None):
+                 metrics_out: str | None = None,
+                 log2_table_size: int | None = None):
     """Train ``n_scenes`` small fields, then serve a mixed request stream
     (scenes x viewpoints) through the RenderEngine — one compiled
     executable for the whole bucket, warmup excluded from latency stats.
+
+    The field is the Table I configuration of ``app``/``encoding``;
+    ``log2_table_size`` replaces its hash-table size (CPU runs use 14).
+    A compile inside the served window raises.
 
     ``occupancy`` serves the ray apps occupancy-culled (DESIGN.md §7):
     training maintains the grid at chunk ends, the engine compacts to
@@ -59,11 +67,11 @@ def serve_render(app: str = "gia", encoding: str = "hash",
     if occupancy and app not in ("nerf", "nvr"):
         raise ValueError(f"--occupancy needs a ray-marched app (nerf/nvr),"
                          f" got {app!r}")
-    base = registry.field_config(app, encoding)
-    # laptop-scale table for the local server (with_grid recomputes the
-    # dependent MLP dims — including nerf's density MLP)
-    cfg = base.with_grid(
-        dataclasses.replace(base.grid, log2_table_size=14))
+    cfg = registry.field_config(app, encoding)
+    if log2_table_size is not None:
+        # with_grid recomputes the dependent MLP dims (nerf's density MLP)
+        cfg = cfg.with_grid(
+            dataclasses.replace(cfg.grid, log2_table_size=log2_table_size))
     qspec = QuantSpec(table_qtype=quant) if quant else None
     if qspec is not None:
         cfg = cfg.with_quant(qspec)
@@ -122,9 +130,10 @@ def serve_render(app: str = "gia", encoding: str = "hash",
     _LOG.info("frame_budget_4k",
               ms_per_frame=round(3840 * 2160 / tile_pixels * med_s * 1e3))
     if stats["n_traces_total"] != len(stats["buckets"]):
-        _LOG.warning("bucket_leak", traces=stats["n_traces_total"],
-                     buckets=len(stats["buckets"]),
-                     hint="camera/scene leaked into the compiled graph")
+        raise RuntimeError(
+            f"{stats['n_traces_total']} traces for "
+            f"{len(stats['buckets'])} buckets: a camera or scene leaked "
+            "into the compiled graph")
     if metrics_out:
         with open(metrics_out, "w") as f:
             f.write(engine.obs.to_json())
@@ -212,6 +221,9 @@ def main(argv=None):
     ap.add_argument("--width", type=int, default=128)
     ap.add_argument("--scenes", type=int, default=2)
     ap.add_argument("--cameras", type=int, default=3)
+    ap.add_argument("--log2-table-size", type=int, default=None,
+                    help="hash-table size of the served field (default: "
+                         "the Table I value; CPU runs use 14)")
     ap.add_argument("--shard", action="store_true",
                     help="pixel-parallel shard_map over the local mesh")
     ap.add_argument("--occupancy", action="store_true",
@@ -233,6 +245,7 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None,
                     help="write the engine metrics snapshot JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.trace_out or args.trace_sync:
         TRACER.enable(sync=args.trace_sync)
     if args.mode == "render":
@@ -244,7 +257,8 @@ def main(argv=None):
                      occupancy=args.occupancy,
                      sample_budget=args.sample_budget,
                      quant=args.quant,
-                     metrics_out=args.metrics_out)
+                     metrics_out=args.metrics_out,
+                     log2_table_size=args.log2_table_size)
     else:
         serve_lm(args.arch, args.reduced)
     if args.trace_out:

@@ -21,6 +21,7 @@ import numpy as np
 from repro.common.partitioning import DEFAULT_RULES
 from repro.configs import registry
 from repro.data import tokens as token_data
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.obs import log as obs_log
 from repro.obs.trace import TRACER
@@ -90,7 +91,9 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--data", type=int, default=1)
+    ap.add_argument("--data", type=int, default=None,
+                    help="data-axis size (default: every local device "
+                         "the model axis leaves)")
     ap.add_argument("--model", type=int, default=1)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
@@ -103,6 +106,7 @@ def main(argv=None):
     ap.add_argument("--metrics-out", default=None,
                     help="write the engine metrics snapshot JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace_out:
         TRACER.enable()

@@ -107,6 +107,12 @@ class Ticket:
     device-ready tickets eagerly on every subsequent ``submit`` so a
     ticket held by the caller does not keep accruing host time."""
 
+    @property
+    def output_sharding(self):
+        """Sharding of the device output: a pixel-parallel engine's
+        output spans every device of its mesh."""
+        return self._out.sharding
+
     def is_ready(self) -> bool:
         try:
             return self._done or bool(self._out.is_ready())

@@ -14,7 +14,7 @@ from __future__ import annotations
 import math
 from typing import Callable, Optional
 
-from jax.experimental.shard_map import shard_map
+import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.common import partitioning
@@ -74,7 +74,7 @@ def shard_tile_fn(tile_fn: Callable, mesh: Mesh,
         return tile_fn
     pix = P(axes)
     rep = P()
-    return shard_map(tile_fn, mesh=mesh,
-                     in_specs=(rep, rep, rep, pix, pix),
-                     out_specs=(pix, pix) if with_aux else pix,
-                     check_rep=False)
+    return jax.shard_map(tile_fn, mesh=mesh,
+                         in_specs=(rep, rep, rep, pix, pix),
+                         out_specs=(pix, pix) if with_aux else pix,
+                         check_vma=False)

@@ -50,7 +50,6 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import store
@@ -143,8 +142,8 @@ def data_parallel_grad_fn(loss_fn: Callable, mesh: Optional[Mesh],
         grads = jax.tree.map(lambda g: jax.lax.pmean(g, names), grads)
         return loss, grads
 
-    return shard_map(local, mesh=mesh, in_specs=(P(), P(axes)),
-                     out_specs=(P(), P()), check_rep=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(), P(axes)),
+                         out_specs=(P(), P()), check_vma=False)
 
 
 def make_scanned_step(loss_fn: Callable, opt_cfg: optim.AdamConfig, *,

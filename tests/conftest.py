@@ -3,14 +3,14 @@ the real single CPU device; multi-device tests spawn subprocesses."""
 import dataclasses
 import sys
 
-# NOTE: the suite is XLA-compile-bound, but do NOT enable JAX's
-# persistent compilation cache here — on jaxlib 0.4.36 CPU a cache *hit*
-# segfaults the process (reproduced via
-# test_system.py::test_lm_train_loop_learns_and_resumes). Tier-1 speed
-# comes from the `slow` marker + shrunk test configs instead.
-
 import jax
 import pytest
+
+# Tests keep JAX's persistent compilation cache off, even where
+# JAX_COMPILATION_CACHE_DIR is set: compiles for a described (absent) TPU
+# would be written to it and could not be read back. Tier-1 speed comes
+# from the `slow` marker and shrunk test configs instead.
+jax.config.update("jax_enable_compilation_cache", False)
 
 # Property tests import `hypothesis`; the hermetic container image may not
 # ship it (it is declared in pyproject's dev extras). Gate in the vendored

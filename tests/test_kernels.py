@@ -338,15 +338,19 @@ def test_fused_field_matches_unfused_apply():
 # ------------------------------------------------------------- ray march
 @pytest.mark.parametrize("r,s", [(64, 16), (500, 32), (256, 192)])
 def test_ray_march_vs_ref(r, s):
-    """Pixels are *bitwise* equal: kernel and render.composite share one
-    exp(cumsum(-sigma*dt)) formulation (DESIGN.md §7). Opacity is a bare
-    row reduction XLA may reassociate — a-few-ulps tolerance."""
+    """Kernel and render.composite share the exp(-prefix-sum(sigma*dt))
+    formulation (DESIGN.md §7), but the kernel sums the prefix as a
+    triangular matmul (Mosaic has no cumsum) while XLA scans it in order.
+    The two summation orders differ by a few f32 ulps in the exponent,
+    so pixels and opacity (values in [0, 1]) agree to atol 5e-7, the
+    opacity bound this test held before the kernel changed."""
     rgb = jax.random.uniform(jax.random.PRNGKey(0), (r, s, 3))
     sigma = jax.random.uniform(jax.random.PRNGKey(1), (r, s)) * 8
     dts = jnp.full((r, s), 0.07)
     pk, ok = rm_ops.composite(rgb, sigma, dts, block_r=128)
     pr, orr = render.composite(rgb, sigma, dts)
-    np.testing.assert_array_equal(np.asarray(pk), np.asarray(pr))
+    np.testing.assert_allclose(np.asarray(pk), np.asarray(pr), atol=5e-7,
+                               rtol=0)
     np.testing.assert_allclose(np.asarray(ok), np.asarray(orr), atol=5e-7,
                                rtol=0)
 
@@ -384,7 +388,8 @@ def test_render_rays_pallas_composite_matches_xla():
     b = render.render_rays(fapply, o, d, n_samples=8,
                            use_pallas_composite=False)
     assert bool(jnp.isfinite(a).all())
-    # shared transmittance formulation -> the routes agree bit-for-bit
+    # shared transmittance formulation; at S=8 the kernel's triangular
+    # matmul and XLA's scan round the prefix sums identically on the CPU
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 

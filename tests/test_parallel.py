@@ -3,6 +3,7 @@ host mesh (subprocess — the main test process keeps 1 device)."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import pytest
@@ -10,22 +11,26 @@ from jax.sharding import PartitionSpec as P
 
 from repro.common.partitioning import (DEFAULT_RULES, divisible_fallback,
                                        rule_preset)
+from repro.launch.mesh import make_mesh
+
+REPO = str(Path(__file__).resolve().parents[1])
 
 
 def _run8(code: str) -> str:
     full = ("import os\n"
             "os.environ['XLA_FLAGS'] = "
             "'--xla_force_host_platform_device_count=8'\n"
-            "import sys; sys.path.insert(0, 'src')\n" + textwrap.dedent(code))
+            "import sys; sys.path.insert(0, 'src')\n"
+            "from repro.launch.mesh import make_mesh\n" + textwrap.dedent(code))
     r = subprocess.run([sys.executable, "-c", full], capture_output=True,
-                       text=True, cwd="/root/repo", timeout=900)
+                       text=True, cwd=REPO, timeout=900)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-4000:])
     return r.stdout
 
 
 def test_divisible_fallback_replicates():
     import numpy as np
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     rules = rule_preset("baseline")
 
     class Shape:
@@ -36,7 +41,7 @@ def test_divisible_fallback_replicates():
 
 
 def test_fallback_logs_record_path():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rules = rule_preset("baseline")
     # 7 not divisible by... size-1 axis always divides; test the log path
     divisible_fallback(mesh, (7,), ("embed",), rules, path="w")
@@ -52,7 +57,7 @@ def test_sharded_train_step_8dev():
         from repro.common.partitioning import rule_preset
         from repro.parallel import api
         from repro.train import optim
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = reduced_config("olmoe-1b-7b")
         rules = rule_preset("baseline")
         step, sh = api.make_train_step(cfg, mesh, rules,
@@ -89,7 +94,7 @@ def test_decode_step_8dev_matches_singledev():
         from repro.parallel import api
         cfg = dataclasses.replace(reduced_config("yi-6b"),
                                   act_dtype="float32")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rules = rule_preset("baseline")
         dec, sh = api.make_decode_step(cfg, mesh, rules, capacity=32,
                                        batch_size=2)
@@ -120,7 +125,7 @@ def test_elastic_restore_across_mesh_shapes():
         from repro.train import optim
         cfg = reduced_config("h2o-danube-1.8b")
         rules = rule_preset("baseline")
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = make_mesh((4, 2), ("data", "model"))
         params = api.init_params(cfg, mesh=mesh1, rules=rules)
         state = {"params": params, "opt": optim.adam_init(params)}
         d = tempfile.mkdtemp()
@@ -152,7 +157,7 @@ def test_compression_in_train_step_8dev():
         from repro.common.partitioning import rule_preset
         from repro.parallel import api
         from repro.train import optim
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = reduced_config("h2o-danube-1.8b")
         from repro.train.optim import AdamConfig
         tc = api.TrainConfig(compression="topk", compression_topk=0.2,
@@ -182,7 +187,7 @@ def test_microbatched_step_matches_plain():
         from repro.common.partitioning import rule_preset
         from repro.parallel import api
         from repro.train import optim
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         import dataclasses
         cfg = dataclasses.replace(reduced_config("yi-6b"),
                                   act_dtype="float32")

@@ -1,9 +1,13 @@
 """Roofline extraction: HLO collective parsing + term math."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.launch import roofline
+
+REPO = str(Path(__file__).resolve().parents[1])
 
 HLO = """
 HloModule jit_f, entry_computation_layout={...}
@@ -89,7 +93,8 @@ def test_parser_on_real_compiled_module():
         import sys
         sys.path.insert(0, "src")
         from repro.launch import roofline
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         W = jax.ShapeDtypeStruct((256, 256), jnp.float32)
         X = jax.ShapeDtypeStruct((64, 256), jnp.float32)
         def f(w, x):
@@ -104,5 +109,5 @@ def test_parser_on_real_compiled_module():
         print("COLLECTIVE_BYTES_OK", got["total"])
     """)
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, cwd="/root/repo")
+                       text=True, cwd=REPO)
     assert "COLLECTIVE_BYTES_OK" in r.stdout, r.stderr[-2000:]

@@ -10,6 +10,7 @@ import dataclasses
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,8 @@ from conftest import small_field_config
 from repro.common.param import unbox
 from repro.core import fields, train
 from repro.train import compression, loop, optim
+
+REPO = str(Path(__file__).resolve().parents[1])
 
 
 # ------------------------------------------------------------ chunk plan
@@ -226,6 +229,7 @@ def test_data_parallel_grads_match_single_device():
             from repro.common.param import unbox
             from repro.common import partitioning
             from repro.core import fields, train
+            from repro.launch.mesh import make_mesh
             from repro.train import loop
 
             cfg = small_field_config('gia', 'hash', log2_T=10, n_levels=2)
@@ -235,7 +239,7 @@ def test_data_parallel_grads_match_single_device():
                 cfg, jax.random.fold_in(k_data, 0), 256)
             loss_fn = lambda p, b: train.field_loss(p, cfg, b)
 
-            mesh = jax.make_mesh((8,), ('data',))
+            mesh = make_mesh((8,), ('data',))
             sharded = loop.data_parallel_grad_fn(
                 loss_fn, mesh, partitioning.DEFAULT_RULES)
             l1, g1 = jax.value_and_grad(loss_fn)(params, batch)
@@ -245,6 +249,6 @@ def test_data_parallel_grads_match_single_device():
                 np.testing.assert_allclose(a, b, atol=1e-5)
             print('OK')
         """)],
-        capture_output=True, text=True, cwd="/root/repo", timeout=900)
+        capture_output=True, text=True, cwd=REPO, timeout=900)
     assert out.returncode == 0, (out.stdout[-2000:], out.stderr[-4000:])
     assert "OK" in out.stdout
