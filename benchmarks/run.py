@@ -22,7 +22,6 @@ from benchmarks.common import Csv  # noqa: E402
 MODULES = [
     ("table1", "benchmarks.table1_models"),
     ("fig5", "benchmarks.fig5_breakdown"),
-    ("fig5_live", "benchmarks.fig5_live"),
     ("fig8", "benchmarks.fig8_encode_ops"),
     ("fig12", "benchmarks.fig12_scaling"),
     ("fig13", "benchmarks.fig13_kernels"),

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.param import Boxed, uniform_init
+from repro.obs.trace import annotate
 
 # instant-NGP's spatial hash primes (pi_1 = 1 keeps coherence in x).
 HASH_PRIMES = (1, 2654435761, 805459861, 3674653429)
@@ -161,9 +162,20 @@ def grid_encode(points: jnp.ndarray, tables: jnp.ndarray,
     engine; on TPU the levels vectorize across the VPU within one chip while
     the *pixels* shard across chips (see DESIGN.md §2).
     """
-    feats = [encode_level(points, tables[l], l, cfg)
-             for l in range(cfg.n_levels)]
+    feats = []
+    for l in range(cfg.n_levels):
+        # one scope per level (DESIGN.md §8): profiles split the encode's
+        # device time by level, by dense or hashed, and (a transpose keeps
+        # its scopes) by forward or backward
+        with annotate(level_scope(cfg, l)):
+            feats.append(encode_level(points, tables[l], l, cfg))
     return jnp.concatenate(feats, axis=-1)
+
+
+def level_scope(cfg: GridConfig, level: int) -> str:
+    """``lvl07_hash``/``lvl00_dense``: the named scope of one level."""
+    kind = "hash" if cfg.level_is_hashed(level) else "dense"
+    return f"lvl{level:02d}_{kind}"
 
 
 # ----------------------------------------------------------------------------

@@ -149,7 +149,7 @@ def apply_field(params: Dict, cfg: FieldConfig, points: jnp.ndarray,
     mlp_p = quant_api.maybe_dequant_mlp(params["mlp"])
 
     # phase scopes (DESIGN.md §8): XLA profiles / HLO metadata carry the
-    # same encode|mlp names the host spans and fig5_live use
+    # encode|mlp taxonomy (and, inside encode, one scope per level)
     barrier = not fused
     if cfg.app == "nerf":
         with annotate("encode"):

@@ -108,6 +108,7 @@ def train_field(cfg: FieldConfig, steps: int = 200, batch_size: int = 2048,
                 compression_topk: float = 0.05,
                 mesh=None, rules=None,
                 on_metrics: Optional[Callable] = None,
+                on_engine: Optional[Callable] = None,
                 n_samples: Optional[int] = None, gt_samples: int = 64,
                 occupancy_res: Optional[int] = None,
                 occupancy_every: int = 1,
@@ -123,7 +124,9 @@ def train_field(cfg: FieldConfig, steps: int = 200, batch_size: int = 2048,
     checkpoint/resume (``ckpt_dir``), gradient accumulation, top-k/int8
     compression of the hash-table gradient, and data-parallel
     ``shard_map`` over the ``field_batch`` mesh axes. ``on_metrics``
-    receives every step's full metrics row (loss, psnr, lr, dt).
+    receives every step's full metrics row (loss, psnr, lr, dt);
+    ``on_engine`` receives the ``TrainEngine`` before it runs (for its
+    ``compiled_chunk``).
 
     Passing ``occupancy_res`` (nerf/nvr only) maintains an occupancy
     grid (DESIGN.md §7) off the engine's ``on_chunk_end`` hook: built
@@ -185,6 +188,8 @@ def train_field(cfg: FieldConfig, steps: int = 200, batch_size: int = 2048,
         if on_metrics:
             on_metrics(i, row, st)
 
+    if on_engine:
+        on_engine(engine)
     state, _ = engine.run(state, on_metrics=_on_metrics)
     out_params = state["params"]
     if occ_box["occ"] is not None:

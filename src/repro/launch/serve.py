@@ -13,7 +13,6 @@
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import jax.numpy as jnp
@@ -177,26 +176,22 @@ def serve_lm(arch: str, reduced: bool = True, batch: int = 2,
             rng.standard_normal((batch, prompt_len, cfg.d_model)),
             cfg.adtype)}
 
-    t0 = time.perf_counter()
-    logits, cache = prefill_fn(params, batch_in, cache)
-    logits.block_until_ready()  # repro: allow[host-sync] prefill timing boundary
-    t_prefill = time.perf_counter() - t0
+    with TRACER.span("lm.prefill", cat="serve", timed=True,
+                     arch=arch) as prefill:
+        logits, cache = prefill_fn(params, batch_in, cache)
+        logits.block_until_ready()  # repro: allow[host-sync] prefill timing boundary
+    t_prefill = prefill.seconds
     out_tokens = []
     tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-    t0 = time.perf_counter()
-    for i in range(gen_len):
-        out_tokens.append(np.asarray(tok)[:, 0])
-        logits, cache = decode_fn(params, cache, tok,
-                                  jnp.int32(prompt_len + i))
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-    jax.block_until_ready(logits)  # repro: allow[host-sync] decode timing boundary
-    t_decode = time.perf_counter() - t0
-    if TRACER.enabled:
-        now = time.perf_counter()
-        TRACER.add_event("lm.prefill", now - t_decode - t_prefill,
-                         now - t_decode, cat="serve", arch=arch)
-        TRACER.add_event("lm.decode", now - t_decode, now, cat="serve",
-                         arch=arch, n_steps=gen_len)
+    with TRACER.span("lm.decode", cat="serve", timed=True, arch=arch,
+                     n_steps=gen_len) as decode:
+        for i in range(gen_len):
+            out_tokens.append(np.asarray(tok)[:, 0])
+            logits, cache = decode_fn(params, cache, tok,
+                                      jnp.int32(prompt_len + i))
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+        jax.block_until_ready(logits)  # repro: allow[host-sync] decode timing boundary
+    t_decode = decode.seconds
     _LOG.info("lm_served", arch=arch, prompt_len=prompt_len,
               prefill_ms=round(t_prefill * 1e3),
               decode_steps=gen_len, decode_ms=round(t_decode * 1e3),
@@ -239,15 +234,12 @@ def main(argv=None):
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome-trace JSON of the run here "
                          "(enables the span tracer)")
-    ap.add_argument("--trace-sync", action="store_true",
-                    help="device-sync at span close for device-complete "
-                         "phase times (slower; implies --trace-out use)")
     ap.add_argument("--metrics-out", default=None,
                     help="write the engine metrics snapshot JSON here")
     args = ap.parse_args(argv)
     enable_compile_cache()
-    if args.trace_out or args.trace_sync:
-        TRACER.enable(sync=args.trace_sync)
+    if args.trace_out:
+        TRACER.enable()
     if args.mode == "render":
         serve_render(args.app, args.encoding, use_pallas=args.use_pallas,
                      train_steps=args.train_steps, n_requests=args.requests,

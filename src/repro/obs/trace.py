@@ -1,30 +1,37 @@
-"""Host-side span tracer with explicit device-sync boundaries.
+"""Host-side span tracer whose spans reach the profiler's clock.
 
-Spans are nested host intervals (thread-local stack) exported as
-Chrome-trace "X" events (``obs/export.py``, viewable in
-``chrome://tracing``/Perfetto). Two sync disciplines:
+Spans are nested host intervals (thread-local stack). Each span goes to
+every sink that is on:
 
-  * **async (default)**: a span measures host time only — submit-side
-    spans on the serve path never call ``block_until_ready``, so tracing
-    cannot perturb XLA's async dispatch. A span's end time is whenever
-    the host leaves the ``with`` block.
-  * **synced** (``TRACER.enable(sync=True)``): a span that ``bind()``-ed
-    a jax value blocks on it at close, so the span covers device
-    completion — the mode ``benchmarks/fig5_live.py`` uses to attribute
-    real serve time to phases.
+  * **buffer**: a bounded in-memory list of Chrome-trace "X" events
+    (``obs/export.py``, viewable in ``chrome://tracing``/Perfetto);
+  * **profile**: a ``jax.profiler.TraceAnnotation`` (a TraceMe) entered
+    and left with the span, so a running profiler session records it on
+    its host plane, on the same clock as the device ops. A TraceMe
+    cannot be back-dated, so spans are live ``with`` blocks.
+
+A span measures host time only: it never syncs the device, so tracing
+cannot perturb XLA's async dispatch. Where a span wraps a wait (the serve
+engine's ``serve.block``, the trainer's ``train.sync``), the wait is the
+program's own.
 
 The process tracer ``TRACER`` is **disabled by default**; a disabled
-``span()`` returns a shared null object (no allocation, no sync — zero
-overhead on hot paths). ``annotate(name)`` is the in-trace counterpart:
-``jax.named_scope`` so XLA profiles / HLO carry the same phase names the
-host spans use (taxonomy: encode|mlp|raymarch|compact|composite|host).
+``span()`` returns a shared null object (no allocation, no sink). A
+``timed=True`` span keeps its ``start``/``end`` stamps whether or not a
+sink is on, so an engine's histograms read the very stamps its spans
+carry (disabled, it is a two-stamp stopwatch and nothing more).
+
+``annotate(name)`` is the in-trace counterpart: ``jax.named_scope`` so
+XLA profiles and HLO ``op_name`` metadata carry the phase names
+(taxonomy: encode|mlp|raymarch|compact|composite, and inside encode one
+``lvlNN_hash``/``lvlNN_dense`` scope per grid level).
 """
 from __future__ import annotations
 
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 
 def annotate(name: str):
@@ -63,63 +70,80 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
-    def bind(self, value):
-        return value
-
 
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_bound",
-                 "_depth", "_parent")
+class Stamps:
+    """A span with no sink: the ``start``/``end`` stamps alone."""
+    __slots__ = ("start", "end")
+
+    def __enter__(self):
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.end = time.perf_counter()
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
+class _Span(Stamps):
+    __slots__ = ("_tracer", "name", "cat", "args", "_depth", "_parent",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
-        self._bound = None
-
-    def bind(self, value):
-        """Attach a jax value; in synced mode the span blocks on it at
-        close so the span covers device completion. Returns ``value``."""
-        self._bound = value
-        return value
+        self._annotation = None
 
     def __enter__(self):
         stack = self._tracer._stack()
         self._depth = len(stack)
         self._parent = stack[-1] if stack else ""
         stack.append(self.name)
-        self._t0 = time.perf_counter()
+        if self._tracer.profile:
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation(self.name,
+                                                            **self.args)
+            self._annotation.__enter__()
+        self.start = time.perf_counter()
         return self
 
-    # repro: sync-boundary synced-span close blocks on the bound value by contract
     def __exit__(self, *exc):
-        if self._tracer.sync and self._bound is not None:
-            import jax
-            jax.block_until_ready(self._bound)
-        t1 = time.perf_counter()
+        self.end = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         self._tracer._stack().pop()
-        self._tracer.add_event(self.name, self._t0, t1, cat=self.cat,
-                               depth=self._depth, parent=self._parent,
-                               **self.args)
+        if self._tracer.buffer:
+            self._tracer.add_event(self.name, self.start, self.end,
+                                   cat=self.cat, depth=self._depth,
+                                   parent=self._parent, **self.args)
         return False
 
 
 class Tracer:
-    """Bounded event buffer + span factory (module docstring)."""
+    """Span factory, its sinks and the bounded event buffer (module
+    docstring)."""
 
     def __init__(self, max_events: int = 200_000):
-        self.enabled = False
-        self.sync = False
+        self.buffer = False
+        self.profile = False
         self.max_events = max_events
         self.dropped = 0
         self._events: List[Dict] = []
         self._epoch = time.perf_counter()
         self._lock = threading.Lock()
         self._local = threading.local()
+
+    @property
+    def enabled(self) -> bool:
+        return self.buffer or self.profile
 
     def _stack(self) -> List[str]:
         stack = getattr(self._local, "stack", None)
@@ -128,13 +152,17 @@ class Tracer:
         return stack
 
     # ------------------------------------------------------------ control
-    def enable(self, sync: bool = False):
-        self.enabled = True
-        self.sync = sync
+    def enable(self, buffer: bool = True, profile: bool = False):
+        """Turn sinks on: ``buffer`` (Chrome-trace events) and/or
+        ``profile`` (TraceAnnotations for a running profiler session)."""
+        if not (buffer or profile):
+            raise ValueError("enable() needs at least one sink")
+        self.buffer = buffer
+        self.profile = profile
 
     def disable(self):
-        self.enabled = False
-        self.sync = False
+        self.buffer = False
+        self.profile = False
 
     def clear(self):
         with self._lock:
@@ -143,19 +171,20 @@ class Tracer:
             self._epoch = time.perf_counter()
 
     # ------------------------------------------------------------- record
-    def span(self, name: str, cat: str = "host", **args):
-        """Context manager for one nested span. Disabled tracer -> the
-        shared null span (no allocation, never syncs)."""
-        if not self.enabled:
-            return _NULL_SPAN
+    def span(self, name: str, cat: str = "host", timed: bool = False,
+             **args):
+        """Context manager for one nested span, sent to every sink that
+        is on. Disabled: the shared null span, or with ``timed`` a bare
+        pair of stamps (``start``, ``end``, ``seconds``)."""
+        if not (self.buffer or self.profile):
+            return Stamps() if timed else _NULL_SPAN
         return _Span(self, name, cat, args)
 
     def add_event(self, name: str, t0: float, t1: float,
                   cat: str = "host", **args):
-        """Record a complete event from explicit ``perf_counter`` stamps
-        (the hot-path API: callers time with their own counters and only
-        call this when ``enabled``)."""
-        if not self.enabled:
+        """Record a complete event from ``perf_counter`` stamps in the
+        buffer sink (only there: the profiler takes live spans)."""
+        if not self.buffer:
             return
         with self._lock:
             if len(self._events) >= self.max_events:
@@ -180,16 +209,6 @@ class Tracer:
         from repro.obs import export as export_mod
         return export_mod.write_chrome_trace(path, self.events(),
                                              dropped=self.dropped)
-
-    def phase_totals(self, cat: Optional[str] = None) -> Dict[str, float]:
-        """Total seconds per span name (optionally one category) —
-        what ``fig5_live`` reduces its synced spans with."""
-        out: Dict[str, float] = {}
-        for ev in self.events():
-            if cat is not None and ev["cat"] != cat:
-                continue
-            out[ev["name"]] = out.get(ev["name"], 0.0) + ev["dur"] / 1e6
-        return out
 
 
 TRACER = Tracer()

@@ -29,15 +29,19 @@ per-request batch, not the frame. This engine is that idea on TPU/XLA:
     the bucket key grows ``(occupancy, sample_budget)`` (the budget
     changes the traced shapes), and ``stats()`` reports the live-sample
     fraction and dropped-sample count next to the effective Mpix/s.
-  * **Observability (DESIGN.md §8).** The engine owns an
-    ``repro.obs.metrics.Registry``: per-bucket ``submit``/``dispatch``/
-    ``block``/``slice`` phase histograms, a ``serve.compiles`` counter
-    fed by the trace-time side effect, and the submit→retire latency
-    histogram that ``stats()``'s p50/p99 now read (warmup excluded, as
-    before). When the process tracer (``repro.obs.trace.TRACER``) is
-    enabled the same phases are emitted as Chrome-trace spans; disabled
-    (the default) the submit path does exactly the ``perf_counter``
-    reads it always did — **no added device syncs**.
+  * **Observability (DESIGN.md §8).** Each request's four phases are
+    live spans of the process tracer (``repro.obs.trace.TRACER``):
+    ``serve.submit`` (request preparation), ``serve.dispatch`` (the
+    jitted call's enqueue), ``serve.block`` (``Ticket.result`` waiting
+    on the device) and ``serve.slice`` (the device-to-host copy and the
+    valid-prefix slice), each with its ``bucket`` and the request's
+    per-engine sequence number ``request``. The engine owns an
+    ``repro.obs.metrics.Registry``: per-bucket phase histograms fed by
+    the spans' own stamps, a ``serve.compiles`` counter fed by the
+    trace-time side effect, and the submit→retire latency histogram
+    that ``stats()``'s p50/p99 read (warmup excluded). Disabled (the
+    default) a span is a pair of ``perf_counter`` stamps — **no added
+    device syncs**.
 
 Register all scenes, then ``warmup()`` (compiles each bucket once, outside
 the latency statistics), then submit the mixed request stream.
@@ -46,7 +50,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
+import itertools
 from typing import Dict, List, Optional
 
 import jax
@@ -57,7 +61,7 @@ from repro.core import pipeline, render
 from repro.core.fields import FieldConfig
 from repro.core.pipeline import RenderSettings
 from repro.obs import metrics as obs_metrics
-from repro.obs.trace import TRACER
+from repro.obs.trace import TRACER, Stamps
 from repro.quant.api import is_quantized_field
 from repro.serve import sharding
 
@@ -120,7 +124,8 @@ class Ticket:
             return True
 
     def __init__(self, engine: "RenderEngine", out, n_valid: int,
-                 t_submit: float, warmup: bool, aux=None, bucket_idx=0):
+                 t_submit: float, warmup: bool, aux=None, bucket_idx=0,
+                 request: int = 0):
         self._engine = engine
         self._out = out
         self._n = n_valid
@@ -128,27 +133,27 @@ class Ticket:
         self._warmup = warmup
         self._aux = aux              # (k, 3) [live, total, dropped] rows
         self._bidx = bucket_idx
+        self.request = request       # the engine's sequence number
         self._res: Optional[np.ndarray] = None
         self._done = False
 
     # repro: sync-boundary result() is THE designated submit/result sync point
     def result(self) -> np.ndarray:
         if not self._done:
-            t_block0 = time.perf_counter()
-            jax.block_until_ready(self._out)
-            t_done = time.perf_counter()
-            self.latency_s = t_done - self._t_submit
-            res = np.asarray(self._out)[:self._n]
-            t_slice = time.perf_counter()
+            eng = self._engine
+            with eng._span("block", self._bidx, self.request,
+                           self._warmup) as block:
+                jax.block_until_ready(self._out)
+            self.latency_s = block.end - self._t_submit
+            with eng._span("slice", self._bidx, self.request,
+                           self._warmup) as sliced:
+                res = np.asarray(self._out)[:self._n]
             if not self._warmup:
-                self._engine._record(self.latency_s, self._n, t_done)
-                self._engine._record_phase(self._bidx, "block",
-                                           t_block0, t_done)
-                self._engine._record_phase(self._bidx, "slice",
-                                           t_done, t_slice)
+                eng._record(self.latency_s, self._n, block.end)
+                eng._record_phase(self._bidx, "block", block)
+                eng._record_phase(self._bidx, "slice", sliced)
                 if self._aux is not None:
-                    self._engine._record_aux(
-                        np.asarray(self._aux).sum(axis=0))
+                    eng._record_aux(np.asarray(self._aux).sum(axis=0))
             self._res = res
             self._done = True
         return self._res
@@ -197,7 +202,7 @@ class RenderEngine:
         self._buckets: Dict[BucketKey, _Bucket] = {}
         self._scene_bucket: Dict[str, BucketKey] = {}
         self._inflight: collections.deque = collections.deque()
-        self._lat: List[float] = []          # exact latencies (compat view)
+        self._requests = itertools.count()   # per-engine request ids
         self._pixels = 0
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
@@ -288,18 +293,27 @@ class RenderEngine:
             bucket.fn = jax.jit(fn)
         return bucket.fn
 
+    def compiled_text(self) -> str:
+        """Optimized HLO text of the first registered bucket's compiled
+        tile program. Camera values are traced data, so any camera gives
+        the same program."""
+        key = next(iter(self._buckets))
+        tp = self.settings.tile_pixels
+        return self._get_fn(key).lower(
+            self._get_stacked(key), jnp.int32(0), _warmup_camera(),
+            jnp.zeros(tp, jnp.int32), jnp.ones(tp, bool)).compile().as_text()
+
     def warmup(self) -> float:
         """Compile every bucket once (dummy request) — excluded from the
         latency statistics, so p50/p99 measure serving, not XLA (the
         warmup-exclusion rule of ``obs.trace.time_fn``)."""
-        t0 = time.perf_counter()
-        cam = render.Camera(height=8, width=8, focal=8.0,
-                            c2w=render.look_at((2.2, 1.6, 1.8), (0, 0, 0)))
-        for key, bucket in self._buckets.items():
-            req = RenderRequest(scene=bucket.order[0], camera=cam,
-                                pixel_ids=np.zeros(1, np.int32))
-            self.submit(req, _warmup=True).result()
-        self._warmup_s += time.perf_counter() - t0
+        with Stamps() as clock:
+            cam = _warmup_camera()
+            for key, bucket in self._buckets.items():
+                req = RenderRequest(scene=bucket.order[0], camera=cam,
+                                    pixel_ids=np.zeros(1, np.int32))
+                self.submit(req, _warmup=True).result()
+        self._warmup_s += clock.seconds
         return self._warmup_s
 
     # ------------------------------------------------------------- serve
@@ -310,38 +324,39 @@ class RenderEngine:
             raise KeyError(f"unknown scene {req.scene!r}")
         bucket = self._buckets[key]
         tp = self.settings.tile_pixels
-        t_prep0 = time.perf_counter()
-        # repro: allow[host-sync] request ids arrive as host numpy, never traced
-        ids = np.asarray(req.pixel_ids, np.int32).ravel()
-        n = ids.shape[0]
-        if n > tp:
-            raise ValueError(f"request has {n} pixels > tile_pixels={tp}; "
-                             "split it (see render_frame)")
-        padded = np.zeros(tp, np.int32)
-        padded[:n] = ids
-        mask = np.zeros(tp, bool)
-        mask[:n] = True
+        request = next(self._requests)
+        with self._span("submit", bucket.idx, request, _warmup,
+                        scene=req.scene) as prep:
+            # repro: allow[host-sync] request ids arrive as host numpy, never traced
+            ids = np.asarray(req.pixel_ids, np.int32).ravel()
+            n = ids.shape[0]
+            if n > tp:
+                raise ValueError(f"request has {n} pixels > tile_pixels="
+                                 f"{tp}; split it (see render_frame)")
+            padded = np.zeros(tp, np.int32)
+            padded[:n] = ids
+            mask = np.zeros(tp, bool)
+            mask[:n] = True
 
-        fn = self._get_fn(key)
-        stacked = self._get_stacked(key)
-        sid = jnp.asarray(bucket.order.index(req.scene), jnp.int32)
-        t0 = time.perf_counter()
+            fn = self._get_fn(key)
+            stacked = self._get_stacked(key)
+            sid = jnp.asarray(bucket.order.index(req.scene), jnp.int32)
+        # host-side spans only: dispatch is the async XLA enqueue —
+        # nothing here blocks on the device
+        with self._span("dispatch", bucket.idx, request,
+                        _warmup) as dispatch:
+            out = fn(stacked, sid, req.camera, jnp.asarray(padded),
+                     jnp.asarray(mask))
         if not _warmup and self._t_first is None:
-            self._t_first = t0
-        out = fn(stacked, sid, req.camera, jnp.asarray(padded),
-                 jnp.asarray(mask))
-        t_dispatched = time.perf_counter()
+            self._t_first = dispatch.start
         aux = None
         if self.settings.occupancy:
             out, aux = out
         if not _warmup:
-            # host-side phase timings only: dispatch is the async XLA
-            # enqueue — nothing here blocks on the device
-            self._record_phase(bucket.idx, "submit", t_prep0, t0,
-                               scene=req.scene)
-            self._record_phase(bucket.idx, "dispatch", t0, t_dispatched)
-        ticket = Ticket(self, out, n, t0, warmup=_warmup, aux=aux,
-                        bucket_idx=bucket.idx)
+            self._record_phase(bucket.idx, "submit", prep)
+            self._record_phase(bucket.idx, "dispatch", dispatch)
+        ticket = Ticket(self, out, n, dispatch.start, warmup=_warmup,
+                        aux=aux, bucket_idx=bucket.idx, request=request)
         self._inflight.append(ticket)
         # retire already-finished work first so its recorded latency is
         # the device completion, not however long the caller sat on it
@@ -371,20 +386,26 @@ class RenderEngine:
 
     # ------------------------------------------------------------- stats
     def _record(self, latency_s: float, n_pixels: int, t_done: float):
-        self._lat.append(latency_s)
         self._lat_hist.record(latency_s)
         self.obs.counter("serve.requests").inc()
         self.obs.counter("serve.pixels").inc(n_pixels)
         self._pixels += n_pixels
         self._t_last = t_done
 
-    def _record_phase(self, bucket_idx: int, phase: str,
-                      t0: float, t1: float, **span_args):
+    @staticmethod
+    def _span(phase: str, bucket_idx: int, request: int, warmup: bool,
+              **args):
+        """The live span of one request's phase; warmup requests get
+        bare stamps and reach no sink."""
+        if warmup:
+            return Stamps()
+        return TRACER.span(f"serve.{phase}", cat="serve", timed=True,
+                           bucket=bucket_idx, request=request, **args)
+
+    def _record_phase(self, bucket_idx: int, phase: str, span):
+        """A phase histogram's sample: its span's own duration."""
         self.obs.histogram(
-            f"serve.{phase}_s.bucket{bucket_idx}").record(t1 - t0)
-        if TRACER.enabled:
-            TRACER.add_event(f"serve.{phase}", t0, t1, cat="serve",
-                             bucket=bucket_idx, **span_args)
+            f"serve.{phase}_s.bucket{bucket_idx}").record(span.seconds)
 
     def _record_aux(self, row: np.ndarray):
         self._samples += row
@@ -395,19 +416,6 @@ class RenderEngine:
     def total_traces(self) -> int:
         return sum(b.n_traces for b in self._buckets.values())
 
-    def exact_percentiles(self, *ps: float) -> List[float]:
-        """Legacy exact order-statistic latencies (seconds) from the
-        compat sample list — the oracle the histogram-derived p50/p99 in
-        ``stats()`` are tested against (within one bucket width)."""
-        lat = sorted(self._lat)
-
-        def pct(p):
-            if not lat:
-                return float("nan")
-            return lat[min(len(lat) - 1, int(round(p / 100.0
-                                                   * (len(lat) - 1))))]
-        return [pct(p) for p in ps]
-
     def stats(self) -> Dict:
         p50_s = self._lat_hist.percentile(50)
         p99_s = self._lat_hist.percentile(99)
@@ -415,7 +423,7 @@ class RenderEngine:
                 if self._t_first is not None and self._t_last is not None
                 else 0.0)
         live, total, dropped = self._samples
-        n_req = len(self._lat)
+        n_req = int(self.obs.counter("serve.requests").value)
         # effective Mpix/s is the *served* throughput — with culling on,
         # the same wall clock serves more pixels, so the win shows up
         # here directly; live_sample_frac explains where it came from.
@@ -447,3 +455,8 @@ class RenderEngine:
                 for k, b in self._buckets.items()},
             "metrics": self.obs.snapshot(),
         }
+
+
+def _warmup_camera() -> render.Camera:
+    return render.Camera(height=8, width=8, focal=8.0,
+                         c2w=render.look_at((2.2, 1.6, 1.8), (0, 0, 0)))

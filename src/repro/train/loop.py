@@ -33,18 +33,22 @@ uninterrupted run, so the two execute identical compiled programs on
 identical inputs — loss trajectories match bitwise, not just to
 tolerance (tests/test_train_engine.py).
 
-Observability (DESIGN.md §8): the engine owns an
-``repro.obs.metrics.Registry``; each completed chunk emits a
-``train.chunk`` span (when the process tracer is enabled), a
-``train.step_s`` histogram sample, and a structured log row, and the
-straggler detector's per-host step-time histograms live in the same
-registry (``health.step_s.<host>``) — one measurement substrate for
-health, metrics snapshots, and Chrome traces.
+Observability (DESIGN.md §8): each chunk is a live ``train.chunk`` span
+of the process tracer (``start``, ``n_steps``, ``host``) holding three
+more: ``train.dispatch`` (the chunk call), ``train.sync`` (the
+``device_get`` of its metrics) and ``train.host`` (everything after that
+before the next chunk call: the metric rows, ``on_metrics``, the
+checkpoint, ``on_chunk_end`` and the health poll). The engine owns an
+``repro.obs.metrics.Registry``; the spans' own stamps feed its
+``train.{dispatch,sync,host}_s`` histograms and the per-step
+``train.step_s`` (dispatch through sync over the chunk's steps), next to
+a structured log row, and the straggler detector's per-host step-time
+histograms live in the same registry (``health.step_s.<host>``) — one
+measurement substrate for health, metrics snapshots, and traces.
 """
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -289,6 +293,7 @@ class TrainEngine:
         self.host = cfg.host or f"host{jax.process_index()}"
         self.events: List = []
         self._chunk_cache: Dict[int, Callable] = {}
+        self._state_shapes = None            # of the state run() last took
 
     # ------------------------------------------------------------- chunks
     def _chunk_fn(self, n: int) -> Callable:
@@ -326,6 +331,15 @@ class TrainEngine:
             fn = jax.jit(chunk, donate_argnums=donate, **kwargs)
         self._chunk_cache[n] = fn
         return fn
+
+    def compiled_chunk(self, n: int):
+        """The compiled program of an ``n``-step chunk of a device-batch
+        engine, lowered for the shapes of the state ``run()`` last took."""
+        if self._state_shapes is None:
+            raise ValueError("run() the engine first: the chunk is lowered "
+                             "for the state it takes")
+        return self._chunk_fn(n).lower(self._state_shapes,
+                                       jnp.int32(0)).compile()
 
     def _host_chunk_iter(self, plan):
         """Prefetched iterator of device-resident stacked chunk batches."""
@@ -370,6 +384,8 @@ class TrainEngine:
                 start = last + 1
                 _LOG.info("resumed", step=last, ckpt_dir=str(cfg.ckpt_dir))
 
+        self._state_shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
         plan = chunk_plan(start, cfg.steps, cfg.chunk_steps)
         prefetch = (self._host_chunk_iter(plan)
                     if self.host_batch_fn is not None else None)
@@ -378,51 +394,59 @@ class TrainEngine:
         try:
             step_hist = self.obs.histogram("train.step_s")
             steps_ctr = self.obs.counter("train.steps")
+            hists = {k: self.obs.histogram(f"train.{k}_s")
+                     for k in ("dispatch", "sync", "host")}
             for (s0, n) in plan:
-                chunk = self._chunk_fn(n)
-                t0 = time.perf_counter()
-                if prefetch is not None:
-                    state, stacked = chunk(state, jnp.int32(s0),
-                                           next(prefetch))
-                else:
-                    state, stacked = chunk(state, jnp.int32(s0))
-                # repro: allow[host-sync] the chunk's one designated sync point
-                stacked = jax.device_get(stacked)
-                dt = time.perf_counter() - t0
-                # the device_get above is the chunk's natural sync point,
-                # so the span/histogram cover device completion without
-                # adding any block_until_ready of their own
-                if TRACER.enabled:
-                    TRACER.add_event("train.chunk", t0,
-                                     t0 + dt, cat="train",
-                                     start=s0, n_steps=n, host=self.host)
-                step_hist.record(dt / n)
-                steps_ctr.inc(n)
+                with TRACER.span("train.chunk", cat="train", start=s0,
+                                 n_steps=n, host=self.host):
+                    chunk = self._chunk_fn(n)
+                    with TRACER.span("train.dispatch", cat="train",
+                                     timed=True) as dispatch:
+                        if prefetch is not None:
+                            state, stacked = chunk(state, jnp.int32(s0),
+                                                   next(prefetch))
+                        else:
+                            state, stacked = chunk(state, jnp.int32(s0))
+                    # the device_get is the chunk's natural sync point, so
+                    # dispatch through sync covers device completion
+                    # without any block_until_ready of the tracer's own
+                    with TRACER.span("train.sync", cat="train",
+                                     timed=True) as sync:
+                        # repro: allow[host-sync] the chunk's one designated sync point
+                        stacked = jax.device_get(stacked)
+                    hists["dispatch"].record(dispatch.seconds)
+                    hists["sync"].record(sync.seconds)
+                    dt = sync.end - dispatch.start
+                    step_hist.record(dt / n)
+                    steps_ctr.inc(n)
+                    with TRACER.span("train.host", cat="train",
+                                     timed=True) as host:
+                        self.monitor.beat(self.host)
+                        self.detector.record(self.host, dt / n)
+                        _LOG.debug("chunk", start=s0, n_steps=n,
+                                   step_ms=round(dt / n * 1e3, 3))
+                        for i in range(n):
+                            row = {k: float(v[i])
+                                   for k, v in stacked.items()}
+                            row["step"] = s0 + i
+                            row["dt"] = dt / n
+                            history.append(row)
+                            if on_metrics is not None:
+                                on_metrics(s0 + i, row, state)
 
-                self.monitor.beat(self.host)
-                self.detector.record(self.host, dt / n)
-                _LOG.debug("chunk", start=s0, n_steps=n,
-                           step_ms=round(dt / n * 1e3, 3))
-                for i in range(n):
-                    row = {k: float(v[i]) for k, v in stacked.items()}
-                    row["step"] = s0 + i
-                    row["dt"] = dt / n
-                    history.append(row)
-                    if on_metrics is not None:
-                        on_metrics(s0 + i, row, state)
-
-                end = s0 + n - 1
-                if ckpt is not None and (
-                        end == cfg.steps - 1
-                        or end - last_saved >= cfg.ckpt_every):
-                    ckpt.save(state, end)   # host snapshot before donation
-                    last_saved = end
-                if self.on_chunk_end is not None:
-                    self.on_chunk_end(end, state)
-                ev = self.policy.poll(end)
-                if ev is not None:
-                    self.events.append(ev)
-                    self.on_event(ev)
+                        end = s0 + n - 1
+                        if ckpt is not None and (
+                                end == cfg.steps - 1
+                                or end - last_saved >= cfg.ckpt_every):
+                            ckpt.save(state, end)   # snapshot before donation
+                            last_saved = end
+                        if self.on_chunk_end is not None:
+                            self.on_chunk_end(end, state)
+                        ev = self.policy.poll(end)
+                        if ev is not None:
+                            self.events.append(ev)
+                            self.on_event(ev)
+                    hists["host"].record(host.seconds)
         finally:
             if prefetch is not None:
                 prefetch.close()

@@ -128,3 +128,57 @@ def test_grad_sparsity_of_hash_tables():
     touched = jnp.any(g != 0, axis=-1)
     frac = float(jnp.mean(touched))
     assert 0 < frac < 0.1   # 8 points touch <= 8*8 rows of 4096
+
+
+def _program(hlo: str) -> str:
+    """Optimized HLO without its module name, metadata and the source
+    tables that metadata points into."""
+    import re
+    out, skip = [], False
+    for line in hlo.splitlines()[1:]:
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            skip = True
+        elif skip and line.startswith("%"):
+            skip = False
+        if not skip:
+            out.append(re.sub(r",?\s*metadata=\{[^}]*\}", "", line))
+    return "\n".join(out)
+
+
+def test_level_scopes_name_each_level_and_leave_the_program_unchanged():
+    """Each level of the encode runs in a ``lvlNN_hash``/``lvlNN_dense``
+    scope that the compiled HLO's op_name keeps inside the ``encode``
+    phase, forward and under the gradient's ``transpose(``; with metadata
+    stripped the optimized HLO equals that of the same levels encoded
+    without scopes."""
+    from repro.obs.trace import annotate
+    cfg = enc.GridConfig(dim=3, n_levels=4, n_features=2,
+                         log2_table_size=8, base_resolution=4, growth=1.5)
+    scopes = [enc.level_scope(cfg, l) for l in range(4)]
+    assert scopes == ["lvl00_dense", "lvl01_hash", "lvl02_hash",
+                      "lvl03_hash"]
+    pts = jax.random.uniform(jax.random.PRNGKey(0), (64, 3))
+    tables = enc.init_grid(jax.random.PRNGKey(1), cfg).value
+
+    def plain(points, t):
+        with annotate("encode"):
+            return jnp.concatenate([enc.encode_level(points, t[l], l, cfg)
+                                    for l in range(cfg.n_levels)], axis=-1)
+
+    def scoped(points, t):
+        with annotate("encode"):
+            return enc.grid_encode(points, t, cfg)
+
+    def grad(f):
+        return jax.grad(lambda points, t: jnp.sum(f(points, t) ** 2), 1)
+
+    texts = {}
+    for name, f, g in (("forward", scoped, plain),
+                       ("grad", grad(scoped), grad(plain))):
+        texts[name] = jax.jit(f).lower(pts, tables).compile().as_text()
+        want = jax.jit(g).lower(pts, tables).compile().as_text()
+        assert _program(texts[name]) == _program(want)
+    for s in scopes:
+        assert f"/encode/{s}/" in texts["forward"]
+        assert f"/transpose(jvp(encode))/{s}/" in texts["grad"]

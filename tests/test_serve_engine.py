@@ -4,6 +4,8 @@ megabatch pad+mask, multi-scene stacking, pixel-parallel sharding.
 Parity bar: engine output == pipeline.render_frame per scene (f32, 1e-5);
 compile bar: a mixed stream (2 scenes x 3 cameras, same bucket) traces the
 bucket executable exactly once."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -193,3 +195,23 @@ def test_engine_rejects_oversized_and_unknown_requests():
         engine.submit(RenderRequest(
             scene="missing", camera=scenes.default_camera(8, 8),
             pixel_ids=np.arange(4, dtype=np.int32)))
+
+
+# -------------------------------------------------------- compiled program
+def test_compiled_text_is_the_tile_program_the_engine_runs(monkeypatch):
+    """The public route to the compiled tile program gives the HLO of the
+    route the benchmark's serve driver takes (``bench/drivers/serve.py``:
+    the first bucket's jitted function lowered for the stacked scenes, a
+    scene id, a camera, pixel ids and a mask), and traces nothing new
+    after warmup."""
+    monkeypatch.syspath_prepend(str(Path(__file__).resolve().parents[1]))
+    from bench.drivers import serve as serve_driver
+
+    cfg = small_field_config("gia", "hash", log2_T=10, n_levels=4)
+    engine = RenderEngine(pipeline.RenderSettings(tile_pixels=64))
+    engine.add_scene("a", cfg, _params(cfg, 0))
+    engine.warmup()
+    traces = engine.total_traces()
+    driver = serve_driver._hlo_texts(engine, 64, _orbit_cam(8, 8, 1.0))
+    assert engine.compiled_text() == driver[0]
+    assert engine.total_traces() == traces

@@ -252,3 +252,43 @@ def test_data_parallel_grads_match_single_device():
         capture_output=True, text=True, cwd=REPO, timeout=900)
     assert out.returncode == 0, (out.stdout[-2000:], out.stderr[-4000:])
     assert "OK" in out.stdout
+
+
+# -------------------------------------------------------- compiled program
+def test_compiled_chunk_is_the_chunk_program_the_engine_runs(monkeypatch):
+    """The public route to a chunk's compiled program, on the engine that
+    ``train_field`` hands out, gives the HLO of the route the benchmark's
+    train driver takes (``bench/drivers/train.py``: the chunk the engine
+    built, lowered for the state's shapes at the first step)."""
+    monkeypatch.syspath_prepend(REPO)
+    from bench.drivers import train as train_driver
+
+    cfg = small_field_config("nvr", "hash", log2_T=8, n_levels=2)
+    engines, chunks, shapes = [], [], {}
+
+    def on_engine(engine):
+        with pytest.raises(ValueError):
+            engine.compiled_chunk(1)
+        engines.append(engine)
+
+    def on_metrics(i, row, st):
+        if i == 0:
+            shapes["state"] = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), st)
+
+    build = train_driver._chunk_builder(loop)
+
+    def keep_chunk(self, n):
+        fn = build(self, n)
+        chunks.append(fn)
+        return fn
+
+    setattr(loop.TrainEngine, train_driver.CHUNK_HOOK, keep_chunk)
+    try:
+        train.train_field(cfg, steps=2, batch_size=32, chunk_steps=1,
+                          on_metrics=on_metrics, on_engine=on_engine,
+                          n_samples=4, gt_samples=4)
+    finally:
+        setattr(loop.TrainEngine, train_driver.CHUNK_HOOK, build)
+    driver = chunks[0].lower(shapes["state"], jnp.int32(0)).compile()
+    assert engines[0].compiled_chunk(1).as_text() == driver.as_text()
