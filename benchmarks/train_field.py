@@ -82,7 +82,7 @@ def run_scan_compare(csv: Csv, app: str = "gia", batch: int = 8192,
         opt_cfg)
     engine = loop.TrainEngine(
         loop.EngineConfig(steps=steps, chunk_steps=chunk_steps),
-        sstep, device_batch_fn=synth)
+        sstep, device_batch_fn=lambda s, state: synth(s))
 
     def fresh_state():
         # chunks donate their input buffers; give each run its own copy

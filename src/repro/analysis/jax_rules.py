@@ -124,7 +124,7 @@ def check_donation() -> List[Finding]:
         new = {"w": state["w"] + 0.1 * jnp.sum(batch)}
         return new, {"loss": jnp.sum(batch)}
 
-    def batch_fn(step):
+    def batch_fn(step, state):
         return jnp.ones((4,), jnp.float32) * step
 
     state = {"w": jnp.zeros((4,), jnp.float32)}

@@ -22,6 +22,7 @@ hardware applies (Section V).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -57,6 +58,12 @@ class GridConfig:
 
     def level_resolution(self, level: int) -> int:
         return int(math.floor(self.base_resolution * self.growth ** level))
+
+    def level_rows(self, level: int) -> int:
+        """R: how many of the level's T rows an index can address — the
+        dense grid's (res+1)^d rows where it fits in T, else all T."""
+        return min(self.table_size,
+                   (self.level_resolution(level) + 1) ** self.dim)
 
     def level_is_hashed(self, level: int) -> bool:
         """Dense 1:1 mapping while the level's grid fits in T, else hash."""
@@ -140,18 +147,88 @@ def encode_level(points: jnp.ndarray, table: jnp.ndarray, level: int,
     cell = jnp.clip(cell.astype(jnp.int32), 0, res - 1)
 
     offsets = _corner_offsets(cfg.dim)  # (C, d) static
-    out = jnp.zeros((points.shape[0], cfg.n_features), jnp.float32)
+    idx = []
     for c in range(offsets.shape[0]):
         corner = cell + offsets[c][None, :]           # (B, d)
         if cfg.level_is_hashed(level):
-            idx = hash_index(corner, cfg.table_size)
+            idx.append(hash_index(corner, cfg.table_size))
         else:
-            idx = dense_index(corner, res, cfg.table_size)
-        feats = jnp.take(table, idx, axis=0)          # (B, F) gather
+            idx.append(dense_index(corner, res, cfg.table_size))
+    corner_feats = gather_corners(cfg.level_rows(level), table, tuple(idx))
+    out = jnp.zeros((points.shape[0], cfg.n_features), jnp.float32)
+    for c, feats in enumerate(corner_feats):
         w = jnp.prod(
             jnp.where(offsets[c][None, :] == 1, frac, 1.0 - frac), axis=-1)
         out = out + w[:, None] * feats.astype(jnp.float32)
     return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def gather_corners(rows: int, table: jnp.ndarray,
+                   idx: Tuple[jnp.ndarray, ...]) -> Tuple[jnp.ndarray, ...]:
+    """The 2^d corner gathers of one level: ``table[idx_c]`` for each c,
+    (T, F) -> C x (B, F). Every index lies in ``[0, rows)``.
+
+    Its table gradient is a sorted-row sum (:func:`row_sum`), not the
+    scatter-add that differentiating ``jnp.take`` gives: XLA's scatter
+    pays for every update it applies, whatever the collisions
+    (DESIGN.md §4, "The encode's backward on the XLA route").
+    """
+    return tuple(jnp.take(table, i, axis=0) for i in idx)
+
+
+def _gather_corners_fwd(rows, table, idx):
+    return gather_corners(rows, table, idx), (table, idx)
+
+
+def _gather_corners_bwd(rows, res, cts):
+    table, idx = res
+    with annotate("rowsum"):
+        grad = row_sum(jnp.concatenate(idx),
+                       jnp.concatenate(cts).astype(jnp.float32), rows)
+        grad = jnp.pad(grad, ((0, table.shape[0] - rows), (0, 0)))
+    return grad.astype(table.dtype), None
+
+
+gather_corners.defvjp(_gather_corners_fwd, _gather_corners_bwd)
+
+
+def row_sum(idx: jnp.ndarray, vals: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """``zeros((rows, F)).at[idx].add(vals)`` without a scatter or a
+    gather: (N,) int32 in ``[0, rows)``, (N, F) f32 -> (rows, F) f32.
+
+    One zero sentinel per row is keyed ``2r+1`` after the updates' ``2i``,
+    so that it sorts last in its row's run; a segmented inclusive sum over
+    runs of one row leaves each row's total on its sentinel; a second sort
+    puts the sentinels first, in row order. Neither sort need be stable
+    (ties only order a row's sum, or the entries past the sentinels), and
+    an unstable TPU sort compiles in half the time.
+    """
+    n_feat = vals.shape[1]
+    r = jnp.arange(rows, dtype=jnp.int32)
+    keys = jnp.concatenate([2 * idx, 2 * r + 1])
+    zeros = jnp.zeros((rows,), vals.dtype)
+    cols = [jnp.concatenate([vals[:, f], zeros]) for f in range(n_feat)]
+    keys, *cols = jax.lax.sort([keys, *cols], num_keys=1, is_stable=False)
+    row = keys >> 1
+    cols = _segmented_sum(row, cols)
+    first = jnp.where((keys & 1) == 1, row, rows)
+    _, *cols = jax.lax.sort([first, *cols], num_keys=1, is_stable=False)
+    return jnp.stack([c[:rows] for c in cols], axis=-1)
+
+
+def _segmented_sum(seg: jnp.ndarray, cols):
+    """Inclusive sum of each column over runs of equal ``seg`` (sorted),
+    by doubling: after the step of shift s an entry holds the sum of the
+    entries of its run within the last 2s, so ceil(log2 N) steps make it
+    the run's prefix sum."""
+    n, s = seg.shape[0], 1
+    while s < n:
+        same = seg == jnp.pad(seg[:-s], (s, 0), constant_values=-1)
+        cols = [c + jnp.where(same, jnp.pad(c[:-s], (s, 0)), 0)
+                for c in cols]
+        s *= 2
+    return cols
 
 
 def grid_encode(points: jnp.ndarray, tables: jnp.ndarray,

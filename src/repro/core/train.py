@@ -8,7 +8,9 @@ train/compression.py for multi-host field training.
 
 ``train_field`` is a thin adapter over the shared training engine
 (``train/loop.py``, DESIGN.md §6): batches are synthesized *on device*
-inside the scanned chunk (batch key = ``fold_in(data_key, step)``), the
+inside the scanned chunk (batch key = ``fold_in(data_key, step)``, the
+data key a leaf of the engine state, so the compiled chunk is the same
+for every seed and JAX's compile cache finds it), the
 ``(params, opt)`` buffers are donated per chunk, and checkpointing,
 gradient compression, and data-parallel sharding ride the same engine
 the LM launcher uses. ``train_field_reference`` keeps the seed per-step
@@ -144,6 +146,7 @@ def train_field(cfg: FieldConfig, steps: int = 200, batch_size: int = 2048,
     k_init, k_data = _data_keys(seed)
     params, _spec = unbox(fields.init_field(k_init, cfg))
     state = loop.init_train_state(params, compression=compression)
+    state["data_key"] = k_data
     opt_cfg = opt_cfg or optim.AdamConfig(lr=1e-2)
     cam = scenes.default_camera() if cfg.app in ("nerf", "nvr") else None
 
@@ -172,8 +175,8 @@ def train_field(cfg: FieldConfig, steps: int = 200, batch_size: int = 2048,
         loop.EngineConfig(steps=steps, chunk_steps=chunk_steps,
                           ckpt_dir=ckpt_dir, ckpt_every=ckpt_every),
         step_fn,
-        device_batch_fn=lambda step: make_batch(
-            cfg, jax.random.fold_in(k_data, step), batch_size, cam,
+        device_batch_fn=lambda step, st: make_batch(
+            cfg, jax.random.fold_in(st["data_key"], step), batch_size, cam,
             gt_samples=gt_samples),
         on_chunk_end=(_refresh_occupancy if occupancy_res is not None
                       else None))

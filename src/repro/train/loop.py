@@ -236,8 +236,10 @@ class TrainEngine:
     ``step_fn(state, step, batch) -> (state, metrics)`` must be pure and
     scannable (metrics: dict of scalars). Exactly one of
 
-      * ``device_batch_fn(step) -> batch`` — traced into the chunk; the
-        fold-in RNG contract lives in the adapter closure, or
+      * ``device_batch_fn(step, state) -> batch`` — traced into the
+        chunk; the fold-in RNG contract lives in the adapter, which reads
+        its data key from the state (so the compiled chunk holds no
+        seed), or
       * ``host_batch_fn(step) -> batch`` — host-side (numpy) per-step
         batches, stacked per chunk and prefetched,
 
@@ -309,7 +311,7 @@ class TrainEngine:
             def chunk(state, start):
                 def body(carry, i):
                     step = start + i
-                    return step_fn(carry, step, batch_fn(step))
+                    return step_fn(carry, step, batch_fn(step, carry))
                 return jax.lax.scan(
                     body, state, jnp.arange(n, dtype=jnp.int32))
 

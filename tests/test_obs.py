@@ -253,7 +253,7 @@ def _train_engine(steps):
         lambda p, b: train_mod.field_loss(p, cfg, b), optim.AdamConfig())
     engine = loop.TrainEngine(
         loop.EngineConfig(steps=steps, chunk_steps=1), step,
-        device_batch_fn=lambda i: train_mod.make_batch(
+        device_batch_fn=lambda i, state: train_mod.make_batch(
             cfg, jax.random.fold_in(jax.random.PRNGKey(1), i), 32))
     engine.run(loop.init_train_state(params))
     return engine

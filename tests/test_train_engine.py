@@ -76,6 +76,55 @@ def test_engine_matches_reference_pallas(app):
         rtol=0, atol=1e-5)
 
 
+def test_sorted_row_sum_trains_like_the_scatter_add(monkeypatch):
+    """Three ``train_field`` steps on nvr/hash give the losses and the
+    parameters of the same steps with the table gradient taken by
+    ``jax.grad`` through plain ``jnp.take`` (XLA's scatter-add), to
+    summation-order rounding: Adam divides each gradient by its own
+    magnitude, so where a row's updates nearly cancel their order shows
+    in that row's step, and the parameters are compared by the norm of
+    their change over the steps."""
+    from repro.core import encoding as enc
+    # level 0 dense (17^3 of 2^14 rows), levels 1-3 hashed
+    cfg = small_field_config("nvr", "hash", log2_T=14, n_levels=4)
+    kw = dict(steps=3, batch_size=256, seed=0, log_every=1, chunk_steps=3,
+              n_samples=8, gt_samples=16)
+
+    def run():
+        losses = []
+        params, _ = train.train_field(
+            cfg, on_metrics=lambda i, row, st: losses.append(row["loss"]),
+            **kw)
+        return np.array(losses), params
+
+    losses, params = run()
+    monkeypatch.setattr(enc, "gather_corners", lambda rows, table, idx:
+                        tuple(jnp.take(table, i, axis=0) for i in idx))
+    ref_losses, ref_params = run()
+    np.testing.assert_allclose(losses, ref_losses, rtol=1e-6)
+    init, _ = unbox(fields.init_field(train._data_keys(0)[0], cfg))
+    for a, b, p0 in zip(jax.tree.leaves(params), jax.tree.leaves(ref_params),
+                        jax.tree.leaves(init)):
+        change = np.linalg.norm(np.asarray(b) - np.asarray(p0))
+        assert change > 0
+        assert np.linalg.norm(np.asarray(a) - np.asarray(b)) <= 1e-5 * change
+
+
+def test_chunk_program_is_the_same_for_every_seed():
+    """The data key is a leaf of the engine state, not a constant of the
+    chunk: two seeds compile one program, which JAX's persistent compile
+    cache then finds again."""
+    cfg = small_field_config("nvr", "hash", log2_T=8, n_levels=2)
+    texts = []
+    for seed in (1, 2):
+        engines = []
+        train.train_field(cfg, steps=1, batch_size=32, seed=seed,
+                          chunk_steps=1, n_samples=4, gt_samples=8,
+                          on_engine=engines.append)
+        texts.append(engines[0].compiled_chunk(1).as_text())
+    assert texts[0] == texts[1]
+
+
 def test_engine_metrics_include_psnr_and_lr():
     cfg = small_field_config("gia", "hash", log2_T=10, n_levels=2)
     rows = []
