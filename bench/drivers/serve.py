@@ -267,8 +267,7 @@ def check(ctx, keep):
 
     @jax.jit
     def weights(key):
-        return scaled_table(ref_field.init_weights(key, cfg),
-                            t["table_range"])
+        return scaled_table(ref.init_weights(key, cfg), t["table_range"])
 
     w = weights(harness.base_key(ctx.seed))
     fns = {}

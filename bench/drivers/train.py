@@ -156,10 +156,11 @@ def reference_steps(ctx, n_steps: int, precision: str, ray_share=1.0):
     keeps that share of each batch's rays (a planted fault)."""
     import jax
     import jax.numpy as jnp
-    from bench.reference import field, nvr
+    from bench.reference import field
 
     t = ctx.traffic
     cfg = ctx.config
+    ref = harness.reference(cfg)
     cam = t["camera"]
     intr = (float(cam["height"]), float(cam["width"]), float(cam["focal"]))
     c2w = field.look_at(cam["eye"])
@@ -168,16 +169,16 @@ def reference_steps(ctx, n_steps: int, precision: str, ray_share=1.0):
 
     @jax.jit
     def step(w, mu, nu, i):
-        b = nvr.batch(jax.random.fold_in(k_data, i), intr, c2w,
+        b = ref.batch(jax.random.fold_in(k_data, i), intr, c2w,
                       t["batch_rays"], t["gt_samples"])
         b = jax.tree.map(lambda x: x[:keep], b)
-        loss, g = jax.value_and_grad(nvr.loss)(w, cfg, b, t["n_samples"],
+        loss, g = jax.value_and_grad(ref.loss)(w, cfg, b, t["n_samples"],
                                               precision)
-        w, mu, nu = nvr.adam(w, g, mu, nu, (i + 1).astype(jnp.float32),
+        w, mu, nu = ref.adam(w, g, mu, nu, (i + 1).astype(jnp.float32),
                              t["adam"])
         return w, mu, nu, loss, g
 
-    w0 = jax.jit(lambda k: field.init_weights(k, cfg))(k_init)
+    w0 = jax.jit(lambda k: ref.init_weights(k, cfg))(k_init)
     zeros = jax.tree.map(jnp.zeros_like, w0)
     w, mu, nu = w0, zeros, zeros
     losses, g0 = [], None

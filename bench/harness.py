@@ -109,6 +109,9 @@ def driver(traffic: dict):
 
 
 def reference(config: dict):
+    """The app's plain reference, ``bench/reference/<app>.py``: its
+    ``init_weights`` and ``render``, and a trained app's ``batch``,
+    ``loss`` and ``adam``."""
     return importlib.import_module(f"bench.reference.{config['app']}")
 
 
@@ -126,13 +129,22 @@ def base_key(seed: int):
 
 def field_config(config: dict):
     """The program's FieldConfig for a configuration file; the file's
-    numbers are used as they stand."""
+    numbers are used as they stand. The grid's L * F features feed the
+    first MLP: ``mlp`` alone, or a ``density_mlp``, whose output goes on
+    beside the direction's ``sh_degree``^2 spherical harmonics to
+    ``mlp``."""
     from repro.core.encoding import GridConfig
     from repro.core.fields import FieldConfig
     from repro.core.mlp import MLPConfig
     g = GridConfig(**config["grid"])
-    return FieldConfig(app=config["app"], grid=g,
-                       mlp=MLPConfig(in_dim=g.out_dim, **config["mlp"]),
+    density = config.get("density_mlp")
+    if density is None:
+        density_mlp, mlp_in = None, g.out_dim
+    else:
+        density_mlp = MLPConfig(in_dim=g.out_dim, **density)
+        mlp_in = config["sh_degree"] ** 2 + density["out_dim"]
+    return FieldConfig(app=config["app"], grid=g, density_mlp=density_mlp,
+                       mlp=MLPConfig(in_dim=mlp_in, **config["mlp"]),
                        name=config["name"])
 
 

@@ -1,8 +1,8 @@
-"""The grid encode's share of its roofline in the train steps, in %: the
-least time of one step's encode, forward and backward (the scatter of the
-table gradient), on one device (bench/work.py) times the steps completed
-in the window, over the device time of the ops in the ``encode`` scope
-and its transpose."""
+"""The encode's share of its roofline in the train steps, in %: the
+least time of one step's grid encode, forward and backward (the sum of the
+table gradient), and direction encode where the field has one, on one
+device (bench/work.py) times the steps completed in the window, over the
+device time of the ops in the ``encode`` scope and its transpose."""
 from bench import work
 
 
@@ -11,9 +11,8 @@ def read(ctx):
     busy = (r or {}).get("phase_s", {}).get("encode")
     if not busy or not c["steps"]:
         return None
-    g = ctx.cell.config["grid"]
     points = c["rays_per_step"] * c["n_samples"] // ctx.chips
     least, _ = work.least_time(
-        work.encode_flops(g, points, backward=True),
-        work.encode_bytes(g, points, backward=True), ctx.peaks)
+        *work.field_encode(ctx.cell.config, points, backward=True),
+        ctx.peaks)
     return 100.0 * c["steps"] * least / busy

@@ -1,7 +1,7 @@
 """The served field's share of the devices' peak, in %: the field's
-operations per point (grid encode and MLP, from the configuration's
-shapes) times the points of the valid pixels held in the window, over the
-window, the chips and the peak bf16 FLOP/s."""
+operations per point (grid encode, direction encode and MLPs, from the
+configuration's shapes) times the points of the valid pixels held in the
+window, over the window, the chips and the peak bf16 FLOP/s."""
 from bench import work
 
 
@@ -10,7 +10,6 @@ def read(ctx):
     points = c["held_pixels"] * c["n_samples"]
     if not points:
         return None
-    g, m = ctx.cell.config["grid"], ctx.cell.config["mlp"]
-    flops = work.field_flops(g, m, points)
+    flops = work.field_flops(ctx.cell.config, points)
     return 100.0 * flops / (c["window_s"] * ctx.chips
                             * ctx.peaks["bf16_flops_per_s"])

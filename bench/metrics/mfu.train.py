@@ -1,8 +1,8 @@
 """The trained field's share of the devices' peak, in %: the field's
-forward and backward operations per point (grid encode and MLP, from the
-configuration's shapes; recomputed work does not count) times the points
-of the steps completed in the window, over their time, the chips and the
-peak bf16 FLOP/s."""
+forward and backward operations per point (grid encode, direction encode
+and MLPs, from the configuration's shapes; recomputed work does not
+count) times the points of the steps completed in the window, over their
+time, the chips and the peak bf16 FLOP/s."""
 from bench import work
 
 
@@ -11,7 +11,6 @@ def read(ctx):
     points = c["steps"] * c["rays_per_step"] * c["n_samples"]
     if not points:
         return None
-    g, m = ctx.cell.config["grid"], ctx.cell.config["mlp"]
-    flops = work.field_flops(g, m, points, backward=True)
+    flops = work.field_flops(ctx.cell.config, points, backward=True)
     return 100.0 * flops / (c["train_s"] * ctx.chips
                             * ctx.peaks["bf16_flops_per_s"])

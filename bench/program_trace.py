@@ -275,7 +275,7 @@ def readings(cell, counts: dict, peaks: dict, chips: int,
       (%): least time of one tile's forward encode over the hashed (dense)
       levels on one device, times the tiles held, over the device time of
       the ``lvl*_hash`` (``lvl*_dense``) scopes;
-    * ``encode_scatter_roofline.train`` (%): least time of one step's
+    * ``encode_transpose_roofline.train`` (%): least time of one step's
       backward encode, times the steps completed, over the device time of
       the level scopes under ``transpose(``;
     * ``host_ms_per_step.train`` (ms): seconds of ``train.dispatch`` and
@@ -301,7 +301,7 @@ def readings(cell, counts: dict, peaks: dict, chips: int,
             least, _ = work.least_time(
                 *encode_work(grid, points, range(grid["n_levels"]),
                              backward=True), peaks)
-            out["encode_scatter_roofline.train"] = (
+            out["encode_transpose_roofline.train"] = (
                 100.0 * counts["steps"] * least / busy)
         host = (span_seconds(program_spans, trace, "train.dispatch")
                 + span_seconds(program_spans, trace, "train.host"))

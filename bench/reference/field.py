@@ -1,10 +1,13 @@
 """The neural field written out plainly: weights from a seed, the
-multi-resolution grid encode, the bias-free MLP, rays and compositing.
+multi-resolution grid encode, the bias-free MLP, rays and compositing,
+and what the ray apps share: rendering through a field, the training
+batch, its loss and the Adam step.
 
 The configuration is the dict of a file under ``bench/configs``. Weights
 follow the published initialisation (instant-NGP): table features
 U(-1e-4, 1e-4), MLP matrices N(0, 1/fan_in), drawn from the key in this
-order: table, then the MLP's input, output and hidden matrices.
+order: table, then each MLP in the order its app's module gives, and in
+each its input, output and hidden matrices.
 Matmuls run at ``precision``: ``"highest"``, full float32, or ``"high"``,
 three bfloat16 passes (each operand split into a bfloat16 high part and a
 bfloat16 remainder, the remainders' product dropped), written out so that
@@ -23,8 +26,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.reference import scene
+
 HASH_PRIMES = (1, 2654435761, 805459861)
 TABLE_INIT = 1e-4
+NEAR, FAR = 0.5, 4.5            # the ray apps' sampled interval
 
 
 def _split(key):
@@ -32,24 +38,39 @@ def _split(key):
     return key, sub
 
 
-def init_weights(key, cfg: dict) -> dict:
-    """{"grid": (L, T, F), "mlp": {"w_in", "w_hidden", "w_out"}}."""
-    g, m = cfg["grid"], cfg["mlp"]
-    key, k_grid = _split(key)
-    key, k_mlp = _split(key)
-    grid = jax.random.uniform(
-        k_grid, (g["n_levels"], 1 << g["log2_table_size"], g["n_features"]),
-        minval=-TABLE_INIT, maxval=TABLE_INIT)
-    in_dim, h = g["n_levels"] * g["n_features"], m["hidden_dim"]
-    k_mlp, k_in = _split(k_mlp)
-    k_mlp, k_out = _split(k_mlp)
-    k_mlp, k_hid = _split(k_mlp)
+def grid_width(grid: dict) -> int:
+    """The encode's output width, L * F."""
+    return grid["n_levels"] * grid["n_features"]
+
+
+def table(key, grid: dict):
+    """The grid's tables (L, T, F), U(-1e-4, 1e-4)."""
+    return jax.random.uniform(
+        key, (grid["n_levels"], 1 << grid["log2_table_size"],
+              grid["n_features"]), minval=-TABLE_INIT, maxval=TABLE_INIT)
+
+
+def mlp_weights(key, in_dim: int, m: dict) -> dict:
+    """{"w_in", "w_hidden", "w_out"} of an MLP of ``in_dim`` inputs, drawn
+    from the key in the order input, output, hidden matrices."""
+    h = m["hidden_dim"]
+    key, k_in = _split(key)
+    key, k_out = _split(key)
+    key, k_hid = _split(key)
     w_in = jax.random.normal(k_in, (in_dim, h)) / jnp.sqrt(float(in_dim))
     w_out = jax.random.normal(k_out, (h, m["out_dim"])) / jnp.sqrt(float(h))
     hidden = jnp.stack([jax.random.normal(k, (h, h)) / jnp.sqrt(float(h))
                         for k in jax.random.split(k_hid, m["n_hidden"] - 1)])
-    return {"grid": grid,
-            "mlp": {"w_in": w_in, "w_hidden": hidden, "w_out": w_out}}
+    return {"w_in": w_in, "w_hidden": hidden, "w_out": w_out}
+
+
+def init_weights(key, cfg: dict) -> dict:
+    """{"grid": (L, T, F), "mlp": {"w_in", "w_hidden", "w_out"}}: a field
+    whose one MLP reads the encode."""
+    key, k_grid = _split(key)
+    key, k_mlp = _split(key)
+    return {"grid": table(k_grid, cfg["grid"]),
+            "mlp": mlp_weights(k_mlp, grid_width(cfg["grid"]), cfg["mlp"])}
 
 
 # ------------------------------------------------------------- encode
@@ -175,3 +196,56 @@ def composite(rgb, sigma, dt):
     trans = jnp.exp(-(jnp.cumsum(depth, axis=-1) - depth))
     weight = trans * (1 - jnp.exp(-depth))
     return jnp.sum(weight[..., None] * rgb, axis=-2)
+
+
+# ---------------------------------------------------------- ray apps
+def render_rays(field_at, origins, dirs, n_samples: int):
+    """Pixels (R, 3) of rays through ``n_samples`` midpoint samples each;
+    ``field_at(points (N, 3) in [0, 1], unit dirs (N, 3))`` gives the
+    samples' (rgb (N, 3), sigma (N,))."""
+    pts, dt = samples(origins, dirs, NEAR, FAR, n_samples)
+    r, s = dt.shape
+    rgb, sigma = field_at(to_unit(pts).reshape(r * s, 3),
+                          jnp.repeat(dirs, s, axis=0))
+    return composite(rgb.reshape(r, s, 3), sigma.reshape(r, s), dt)
+
+
+def render_pixels(field_at, intrinsics, c2w, ids, n_samples: int,
+                  precision: str):
+    """Pixels (R, 3) of flat ids seen by the camera."""
+    origins, dirs = rays(intrinsics, c2w, ids, precision)
+    return render_rays(field_at, origins, dirs, n_samples)
+
+
+def ray_batch(key, intrinsics, c2w, n_rays: int, gt_samples: int):
+    """Random pixels of the training camera and their analytic colours:
+    the key splits into the pixel key and the stratification key."""
+    k_pix, k_strat = jax.random.split(key)
+    hw = jnp.int32(int(intrinsics[0]) * int(intrinsics[1]))
+    ids = jax.random.randint(k_pix, (n_rays,), 0, hw)
+    origins, dirs = rays(intrinsics, c2w, ids, "highest")
+    u = jax.random.uniform(k_strat, (n_rays, gt_samples))
+    pts, dt = samples(origins, dirs, NEAR, FAR, gt_samples, u)
+    world = to_unit(pts) * 4.0 - 2.0
+    rgb, sigma = scene.volume(world, dirs)
+    return origins, dirs, composite(rgb, sigma, dt)
+
+
+def ray_loss(field_at, b, n_samples: int):
+    """Mean squared error of a batch's rendered pixels."""
+    origins, dirs, target = b
+    return jnp.mean((render_rays(field_at, origins, dirs, n_samples)
+                     - target) ** 2)
+
+
+def adam(w, grads, mu, nu, step: int, opt: dict):
+    """One Adam step (bias-corrected, no weight decay); ``step`` counts
+    from 1."""
+    b1, b2, eps, lr = opt["b1"], opt["b2"], opt["eps"], opt["lr"]
+    mu = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g, mu, grads)
+    nu = jax.tree.map(lambda v, g: b2 * v + (1 - b2) * g * g, nu, grads)
+    c1, c2 = 1 - b1 ** step, 1 - b2 ** step
+    w = jax.tree.map(
+        lambda p, m, v: (p - lr * (m / c1) / (jnp.sqrt(v / c2) + eps)
+                         ).astype(p.dtype), w, mu, nu)
+    return w, mu, nu

@@ -8,6 +8,8 @@ import jax.numpy as jnp
 
 from bench.reference import field
 
+init_weights = field.init_weights
+
 
 def render(w: dict, cfg: dict, intrinsics, c2w, ids, n_samples: int,
            precision: str):

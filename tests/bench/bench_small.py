@@ -2,6 +2,7 @@
 same configuration and traffic files, with the table, the tiles, the
 images and the batch made small. Only the tests use this."""
 import copy
+import json
 import sys
 import time
 from pathlib import Path
@@ -13,10 +14,39 @@ if str(ROOT) not in sys.path:
 from bench import harness  # noqa: E402
 
 
+FIXTURES = Path(__file__).resolve().parent / "fixtures"
+
+# cells that only files under tests/bench/fixtures define, as a later
+# cell of BENCHMARK.json would: its configuration file, its traffic mix,
+# the cell whose metrics it reports, and its limits (the tests' own, set
+# from CPU readings at the tests' sizes)
+FIXTURE_CELLS = {
+    "nerf_hash.tiles": ("nerf_hash", "tiles", "nvr_hash.tiles",
+                        {"pixel_gap": 1e-5}),
+    "nerf_hash.train": ("nerf_hash", "train", "nvr_hash.train",
+                        {"loss_gap": 1e-4, "grad_gap": 2e-4,
+                         "change_gap": 5e-3}),
+}
+
+
+def load_cell(name: str) -> "harness.Cell":
+    """A cell of BENCHMARK.json or of FIXTURE_CELLS, by name."""
+    if name not in FIXTURE_CELLS:
+        return harness.load_cell(name)
+    config, traffic, like, limits = FIXTURE_CELLS[name]
+    base = harness.load_cell(like)
+    return harness.Cell(
+        name, base.chips,
+        json.loads((FIXTURES / f"{config}.json").read_text()),
+        json.loads((harness.BENCH / "traffic" / f"{traffic}.json")
+                   .read_text()),
+        dict(limits), base.end_to_end, base.per_layer)
+
+
 def small_cell(cell, log2_table_size: int = 10,
                n_levels: int = 4) -> "harness.Cell":
     """A cell, by name or loaded, cut to a tiny size."""
-    cell = (harness.load_cell(cell) if isinstance(cell, str)
+    cell = (load_cell(cell) if isinstance(cell, str)
             else copy.deepcopy(cell))
     cell.config = copy.deepcopy(cell.config)
     cell.config["grid"]["log2_table_size"] = log2_table_size

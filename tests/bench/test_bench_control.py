@@ -13,7 +13,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 from bench_small import rehearse, small_cell  # noqa: E402
 
-SERVE_ONE_CHIP = ["nvr_hash.tiles", "gia_hash.pan"]
+SERVE_ONE_CHIP = ["nvr_hash.tiles", "gia_hash.pan", "nerf_hash.tiles"]
 
 
 def _serve_cell(name):
@@ -48,10 +48,12 @@ def test_the_control_separates_from_the_program_serve(name):
     assert r["checks"]["pixel_gap"]["value"] >= 3 * max(program, 1e-7)
 
 
-def test_the_control_separates_from_the_program_train():
-    r = rehearse(small_cell("nvr_hash.train"), control=True)
+@pytest.mark.parametrize("name", ["nvr_hash.train", "nerf_hash.train"])
+def test_the_control_separates_from_the_program_train(name):
+    r = rehearse(small_cell(name), control=True)
     _control_fails(r)
-    ratios = [r["checks"][k]["value"] / r["counts"][f"program.{k}"]
+    ratios = [r["checks"][k]["value"] / max(r["counts"][f"program.{k}"],
+                                            1e-12)
               for k in ("loss_gap", "grad_gap", "change_gap")]
     assert max(ratios) >= 3
     # half of the batch left out reads far above the program

@@ -11,7 +11,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 from bench_small import rehearse, small_cell  # noqa: E402
 
-SERVE_ONE_CHIP = ["nvr_hash.tiles", "gia_hash.pan"]
+SERVE_ONE_CHIP = ["nvr_hash.tiles", "gia_hash.pan", "nerf_hash.tiles"]
+TRAIN_ONE_CHIP = ["nvr_hash.train", "nerf_hash.train"]
 
 
 def _failed(r: dict, *names):
@@ -33,7 +34,8 @@ def test_an_altered_pixel_fails(name, monkeypatch):
     _failed(rehearse(small_cell(name)), "pixel_gap")
 
 
-def test_a_step_that_keeps_its_state_fails(monkeypatch):
+@pytest.mark.parametrize("name", TRAIN_ONE_CHIP)
+def test_a_step_that_keeps_its_state_fails(name, monkeypatch):
     from repro.train import optim
     update = optim.adam_update
 
@@ -42,10 +44,11 @@ def test_a_step_that_keeps_its_state_fails(monkeypatch):
         return params, state, metrics
 
     monkeypatch.setattr(optim, "adam_update", unchanged)
-    _failed(rehearse(small_cell("nvr_hash.train")), "grad_gap", "change_gap")
+    _failed(rehearse(small_cell(name)), "grad_gap", "change_gap")
 
 
-def test_half_the_batch_fails(monkeypatch):
+@pytest.mark.parametrize("name", TRAIN_ONE_CHIP)
+def test_half_the_batch_fails(name, monkeypatch):
     import jax
     from repro.core import train as train_mod
     loss = train_mod.field_loss
@@ -55,5 +58,4 @@ def test_half_the_batch_fails(monkeypatch):
         return loss(params, cfg, jax.tree.map(lambda x: x[:n], batch), **k)
 
     monkeypatch.setattr(train_mod, "field_loss", half)
-    _failed(rehearse(small_cell("nvr_hash.train")),
-            "loss_gap", "grad_gap", "change_gap")
+    _failed(rehearse(small_cell(name)), "loss_gap", "grad_gap", "change_gap")

@@ -74,6 +74,54 @@ def test_no_cell_or_metric_named_in_the_code():
             assert n not in text, f"{n} named in {path}"
 
 
+def test_no_app_module_named_outside_the_lookup():
+    """The drivers take every weight and reference function from
+    ``harness.reference(config)``; only the reference's shared modules are
+    imported by name."""
+    shared = {"field", "scene"}
+    named = re.compile(r"bench\.reference\.(\w+)"
+                       r"|from bench\.reference import ([\w, ]+)")
+    for path in (ROOT / "bench").rglob("*.py"):
+        for m in named.finditer(path.read_text()):
+            modules = {n.split()[0] for n in
+                       (m.group(1) or m.group(2)).split(",")}
+            assert modules <= shared, f"{m.group(0)} in {path}"
+
+
+def _file_config(name):
+    path = (ROOT / "tests" / "bench" / "fixtures" / f"{name}.json"
+            if name == "nerf_hash"
+            else ROOT / "bench" / "configs" / f"{name}.json")
+    return json.loads(path.read_text())
+
+
+@pytest.mark.parametrize("name", ["nvr_hash", "gia_hash", "nerf_hash"])
+def test_field_config_is_table_one(name):
+    """The FieldConfig built from a configuration file at Table I widths
+    is the program's own Table I row."""
+    import dataclasses
+    from repro.core import fields
+    config = _file_config(name)
+    want = fields.make_field_config(config["app"], config["grid"]["kind"])
+    got = harness.field_config(config)
+    assert got == dataclasses.replace(want, name=got.name)
+
+
+@pytest.mark.parametrize("name", ["nvr_hash", "gia_hash"])
+def test_field_config_of_one_mlp_is_unchanged(name):
+    """A file without a density MLP gives the FieldConfig it gave before
+    fields of two MLPs were read: one MLP on the grid's L * F."""
+    from repro.core.encoding import GridConfig
+    from repro.core.fields import FieldConfig
+    from repro.core.mlp import MLPConfig
+    config = _file_config(name)
+    g = GridConfig(**config["grid"])
+    assert harness.field_config(config) == FieldConfig(
+        app=config["app"], grid=g,
+        mlp=MLPConfig(in_dim=g.out_dim, **config["mlp"]),
+        name=config["name"])
+
+
 def test_run_refuses_a_cpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     r = subprocess.run(

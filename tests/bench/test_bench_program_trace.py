@@ -210,9 +210,9 @@ def test_readings_of_the_fixture():
     counts = {"steps": 2, "rays_per_step": 16, "n_samples": 4}
     got = pt.readings(cell, counts, peaks.peaks_for("TPU v5 lite"), 1,
                       level_s, trace["program_spans"], trace)
-    assert set(got) == {"encode_scatter_roofline.train",
+    assert set(got) == {"encode_transpose_roofline.train",
                         "host_ms_per_step.train"}
-    assert 0 < got["encode_scatter_roofline.train"] < 100
+    assert 0 < got["encode_transpose_roofline.train"] < 100
     host = (pt.span_seconds(trace["program_spans"], trace, "train.dispatch")
             + pt.span_seconds(trace["program_spans"], trace, "train.host"))
     assert got["host_ms_per_step.train"] == pytest.approx(host / 2 * 1e3)
