@@ -5,10 +5,13 @@ direction input to a second (color) MLP. All graphs support the three
 encoding types (hash / dense / tiled grid) — app x encoding = the 12
 configurations of Table I.
 
-`fused=True` routes encode+MLP through the Pallas fused-field kernel (the
-NFP: one pallas_call, features never leave VMEM). `fused=False` is the
-GPU-baseline structure: encode materializes its output (optimization
-barrier = the DRAM round trip of Fig. 7), then the MLP reads it back.
+`apply_field` has two routes. `use_pallas=True` runs encode+MLP in the
+Pallas fused-field kernel (the NFP: one pallas_call, features never leave
+VMEM). Otherwise XLA runs them, and `fused` picks the structure there:
+`fused=True` lets XLA fuse across the two, `fused=False` is the
+GPU-baseline structure, where encode materializes its output (an
+optimization barrier = the DRAM round trip of Fig. 7) and the MLP reads it
+back.
 """
 from __future__ import annotations
 
@@ -149,17 +152,18 @@ def apply_field(params: Dict, cfg: FieldConfig, points: jnp.ndarray,
     mlp_p = quant_api.maybe_dequant_mlp(params["mlp"])
 
     # phase scopes (DESIGN.md §8): XLA profiles / HLO metadata carry the
-    # encode|mlp taxonomy (and, inside encode, one scope per level)
+    # encode|mlp taxonomy (and, inside encode, one scope per level); nerf's
+    # sub-scopes are one word each, so the enclosing phase still names them
     barrier = not fused
     if cfg.app == "nerf":
         with annotate("encode"):
             h = _encode(points, tables, cfg.grid, barrier)
-        with annotate("mlp"):
+        with annotate("mlp"), annotate("density_mlp"):
             dfeat = apply_mlp(dmlp, h, cfg.density_mlp)
             sigma = jnp.exp(dfeat[:, :1])      # instant-NGP exp activation
-        with annotate("encode"):
+        with annotate("encode"), annotate("sh_encode"):
             sh = enc.sh_encode(dirs)
-        with annotate("mlp"):
+        with annotate("mlp"), annotate("color_mlp"):
             color_in = jnp.concatenate([sh, dfeat], axis=-1)
             rgb = jax.nn.sigmoid(apply_mlp(mlp_p, color_in,
                                            cfg.mlp))
