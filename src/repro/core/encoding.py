@@ -147,14 +147,18 @@ def encode_level(points: jnp.ndarray, table: jnp.ndarray, level: int,
     cell = jnp.clip(cell.astype(jnp.int32), 0, res - 1)
 
     offsets = _corner_offsets(cfg.dim)  # (C, d) static
-    idx = []
-    for c in range(offsets.shape[0]):
-        corner = cell + offsets[c][None, :]           # (B, d)
-        if cfg.level_is_hashed(level):
-            idx.append(hash_index(corner, cfg.table_size))
-        else:
-            idx.append(dense_index(corner, res, cfg.table_size))
-    corner_feats = gather_corners(cfg.level_rows(level), table, tuple(idx))
+    if cfg.level_is_hashed(level):
+        idx = tuple(hash_index(cell + offsets[c][None, :], cfg.table_size)
+                    for c in range(offsets.shape[0]))
+        shifts = ((0,),) * len(idx)
+    else:
+        # dense_index is row-major and masked by T: corner c lies a fixed
+        # row offset from corner 0, modulo the level's rows, so one index
+        # names the whole cell
+        idx = (dense_index(cell, res, cfg.table_size),)
+        strides = (res + 1) ** np.arange(cfg.dim)
+        shifts = (tuple(int(s) for s in offsets @ strides),)
+    corner_feats = gather_corners(cfg.level_rows(level), shifts, table, idx)
     out = jnp.zeros((points.shape[0], cfg.n_features), jnp.float32)
     for c, feats in enumerate(corner_feats):
         w = jnp.prod(
@@ -163,28 +167,57 @@ def encode_level(points: jnp.ndarray, table: jnp.ndarray, level: int,
     return out
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def gather_corners(rows: int, table: jnp.ndarray,
-                   idx: Tuple[jnp.ndarray, ...]) -> Tuple[jnp.ndarray, ...]:
-    """The 2^d corner gathers of one level: ``table[idx_c]`` for each c,
-    (T, F) -> C x (B, F). Every index lies in ``[0, rows)``.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def gather_corners(rows: int, shifts: Tuple[Tuple[int, ...], ...],
+                   table: jnp.ndarray, idx: Tuple[jnp.ndarray, ...]
+                   ) -> Tuple[jnp.ndarray, ...]:
+    """The 2^d corner rows of one level, (T, F) -> C x (B, F): index
+    ``idx[k]`` names the rows ``(idx[k] + s) mod rows`` for each ``s`` of
+    ``shifts[k]``, in corner order. Every index lies in ``[0, rows)``.
 
-    Its table gradient is a sorted-row sum (:func:`row_sum`), not the
-    scatter-add that differentiating ``jnp.take`` gives: XLA's scatter
-    pays for every update it applies, whatever the collisions
-    (DESIGN.md §4, "The encode's backward on the XLA route").
+    A hashed level gives each corner its own index and the shift 0: a
+    plain ``jnp.take`` of the table. A non-hashed level gives one index,
+    its cell's corner 0, and each corner's row offset: it takes one row of
+    a brick (:func:`_brick`, under a ``brick`` scope) whose row i holds
+    the cell's 2^d rows side by side, so one index fetches what 2^d did.
+    The values are the table's, so the result is bitwise that of the
+    per-corner takes.
+
+    Its table gradient is a sorted-row sum (:func:`row_sum`) over the
+    corners' rows, not the scatter-add that differentiating ``jnp.take``
+    gives: XLA's scatter pays for every update it applies, whatever the
+    collisions (DESIGN.md §4, "The encode's backward on the XLA route").
+    The brick has no gradient path of its own.
     """
-    return tuple(jnp.take(table, i, axis=0) for i in idx)
+    n_feat, feats = table.shape[1], []
+    for i, s in zip(idx, shifts):
+        got = jnp.take(_brick(table, rows, s), i, axis=0)
+        feats.extend(got[:, k * n_feat:(k + 1) * n_feat]
+                     for k in range(len(s)))
+    return tuple(feats)
 
 
-def _gather_corners_fwd(rows, table, idx):
-    return gather_corners(rows, table, idx), (table, idx)
+def _brick(table: jnp.ndarray, rows: int,
+           shifts: Tuple[int, ...]) -> jnp.ndarray:
+    """(T, F) -> (rows, len(shifts) F): row i holds ``table[(i + s) mod
+    rows]`` for each s, side by side; the table itself for the shift 0."""
+    if shifts == (0,):
+        return table
+    with annotate("brick"):
+        return jnp.concatenate(
+            [jnp.roll(table[:rows], -s, axis=0) for s in shifts], axis=-1)
 
 
-def _gather_corners_bwd(rows, res, cts):
+def _gather_corners_fwd(rows, shifts, table, idx):
+    return gather_corners(rows, shifts, table, idx), (table, idx)
+
+
+def _gather_corners_bwd(rows, shifts, res, cts):
     table, idx = res
     with annotate("rowsum"):
-        grad = row_sum(jnp.concatenate(idx),
+        corner_rows = [i if s == 0 else (i + s) % rows
+                       for i, ss in zip(idx, shifts) for s in ss]
+        grad = row_sum(jnp.concatenate(corner_rows),
                        jnp.concatenate(cts).astype(jnp.float32), rows)
         grad = jnp.pad(grad, ((0, table.shape[0] - rows), (0, 0)))
     return grad.astype(table.dtype), None

@@ -273,13 +273,56 @@ def test_grid_encode_gradient_equals_take_scatter_add(kind, dim):
     np.testing.assert_array_equal(gt[untouched], 0.0)
 
 
+# two or three levels each, with points at 0.0 and 1.0 on every axis and
+# at every corner of the unit cube: hash mixes dense and hashed levels;
+# dense and tiled levels hold more than T rows in 3-D, and tiled in 2-D
+# too, so that their index wraps into T
+FORWARD_CASES = {
+    "hash": lambda d: enc.GridConfig(dim=d, n_levels=3, n_features=2,
+                                     log2_table_size=8, base_resolution=2,
+                                     growth=3.0, kind="hash"),
+    "dense": lambda d: enc.GridConfig(dim=d, n_levels=3, n_features=2,
+                                      log2_table_size=10, base_resolution=2,
+                                      growth=3.0, kind="dense"),
+    "tiled": lambda d: enc.GridConfig(dim=d, n_levels=2, n_features=8,
+                                      log2_table_size=8, base_resolution=20,
+                                      growth=1.0, kind="tiled"),
+}
+
+
+@pytest.mark.parametrize("kind", ["hash", "dense", "tiled"])
+@pytest.mark.parametrize("dim", [2, 3])
+def test_grid_encode_forward_equals_per_corner_takes(kind, dim):
+    """``grid_encode`` (one brick row per point on a non-hashed level) is
+    bitwise the encode written as 2^d plain ``jnp.take`` per level."""
+    cfg = FORWARD_CASES[kind](dim)
+    hashed = [cfg.level_is_hashed(l) for l in range(cfg.n_levels)]
+    assert any(hashed) == (kind == "hash") and not all(hashed)
+    wraps = [(cfg.level_resolution(l) + 1) ** dim > cfg.table_size
+             for l in range(cfg.n_levels)]
+    assert any(w and not h for w, h in zip(wraps, hashed)) == (
+        kind == "tiled" or (kind == "dense" and dim == 3))
+    cube = enc._corner_offsets(dim).astype(np.float32)
+    pts = jnp.concatenate([
+        jax.random.uniform(jax.random.PRNGKey(0), (256, dim)),
+        jnp.asarray(cube), jnp.full((1, dim), 0.5).at[0, 0].set(0.0),
+        jnp.full((1, dim), 0.5).at[0, -1].set(1.0)])
+    tables = jax.random.normal(jax.random.PRNGKey(1),
+                               (cfg.n_levels, cfg.table_size,
+                                cfg.n_features))
+    got = jax.jit(enc.grid_encode, static_argnums=2)(pts, tables, cfg)
+    want = jax.jit(_take_encode, static_argnums=2)(pts, tables, cfg)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("kind", ["hash", "dense", "tiled"])
 @pytest.mark.parametrize("dim", [2, 3])
 def test_grid_encode_forward_is_the_take_program_and_its_gradient_sorts(
         kind, dim):
-    """With metadata stripped the forward's optimized HLO equals that of
-    plain ``jnp.take``; the gradient's holds each level's sorts under
-    ``transpose(jvp(encode))/lvlNN_kind/rowsum/`` and no scatter."""
+    """In each level's scope the forward's optimized HLO holds 2^d gathers
+    on a hashed level, and one gather of a brick built under a ``brick``
+    scope on a non-hashed one; the gradient's holds each level's sorts
+    under ``transpose(jvp(encode))/lvlNN_kind/rowsum/`` and no scatter."""
     from repro.obs.trace import annotate
     cfg, pts, tables, ct = _grad_case(kind, dim)
 
@@ -296,7 +339,13 @@ def test_grid_encode_forward_is_the_take_program_and_its_gradient_sorts(
         return jax.jit(f).lower(pts, tables).compile().as_text()
 
     forward = text(scoped(enc.grid_encode))
-    assert _program(forward) == _program(text(scoped(_take_encode)))
+    for l in range(cfg.n_levels):
+        scope = f"/encode/{enc.level_scope(cfg, l)}/"
+        gathers = [line for line in forward.splitlines()
+                   if " gather(" in line and scope in line]
+        hashed = cfg.level_is_hashed(l)
+        assert len(gathers) == (2 ** dim if hashed else 1), scope
+        assert (f"{scope}brick/" in forward) == (not hashed), scope
     assert " scatter(" in text(grad(scoped(_take_encode)))
     backward = text(grad(scoped(enc.grid_encode)))
     assert " scatter(" not in backward

@@ -98,8 +98,10 @@ def test_sorted_row_sum_trains_like_the_scatter_add(monkeypatch):
         return np.array(losses), params
 
     losses, params = run()
-    monkeypatch.setattr(enc, "gather_corners", lambda rows, table, idx:
-                        tuple(jnp.take(table, i, axis=0) for i in idx))
+    monkeypatch.setattr(
+        enc, "gather_corners", lambda rows, shifts, table, idx: tuple(
+            jnp.take(table, (i + s) % rows, axis=0)
+            for i, ss in zip(idx, shifts) for s in ss))
     ref_losses, ref_params = run()
     np.testing.assert_allclose(losses, ref_losses, rtol=1e-6)
     init, _ = unbox(fields.init_field(train._data_keys(0)[0], cfg))
